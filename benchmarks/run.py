@@ -12,6 +12,8 @@ import os
 import sys
 import time
 
+from repro.compat import use_compile_cache
+
 from benchmarks import (bench_batch_updates, bench_block_sweep, bench_build,
                         bench_extremes, bench_maintenance, bench_scaling,
                         bench_serve, bench_sig_store, bench_stream)
@@ -61,6 +63,7 @@ def main() -> None:
     ap.add_argument("--no-json", action="store_true",
                     help="skip writing BENCH_<name>.json files")
     args = ap.parse_args()
+    use_compile_cache()
 
     print("name,us_per_call,derived")
     t_start = time.perf_counter()

@@ -25,16 +25,19 @@ event at every one of them so tests and benchmarks can count.
   signature->rank program per iteration, draining scalars every
   ``sync_every`` iterations.
 * **Fused maintenance** (``propagate_levels_resident``): all k levels of
-  the frontier fold + store probe/mint/insert unroll into ONE jitted
-  program; in the steady state (no partition change) a whole propagate
-  costs one gather, one upload, one dispatch and one two-scalar sync.
-  The first level that actually changes falls back down the ladder.
+  the frontier fold + store probe unroll into ONE jitted program; in
+  the steady state (no partition change) a whole propagate costs one
+  gather, one upload, one dispatch and one k-vector scalar sync.  The
+  first level that actually changes mints its novel pids on the host
+  and merges them into the device store; later levels fall back down
+  the ladder.
 * **Fallback ladder**: fused k-loop -> per-level device-fused
   (``resident_level_resolve``) -> staged device (probe/resolve/merge as
   separate programs) -> pure host.  Every rung is bit-identical to the
   host reference (asserted by tests/test_fused_build.py and the update
-  fuzz harness); a device failure permanently degrades the maintainer to
-  the next rung, never changes results.
+  fuzz harness); a transient device fault (`TransientIOError`)
+  permanently degrades the maintainer to the host rung, never changes
+  results, and any other device error propagates.
 * **Bucketing policy**: all device batch shapes are padded to
   ``device_maint.bucket(n)`` — the next power of two, floored at
   ``BUCKET_FLOOR`` — so padding waste stays under 2x while the compiled

@@ -16,34 +16,32 @@ path the maintainer switches to with ``device=True``:
     hash measurably wins.  A per-frontier cache keeps the fold's device
     constants (labels, boundaries, pId_0) resident across levels.  In
     multiset mode with ``use_kernel=True`` the fold routes through the
-    Pallas `kernels.sig_fold` (single-block segmented sum).
+    Pallas `kernels.sig_fold.frontier_sig_fold` (tiled segmented scan).
 
   * `DeviceSigStore` — a device mirror of the array-backed `SigStore`:
     the sorted (hi, lo) u32 key lanes and the int32 pid column live as
     device arrays padded to a power-of-two capacity with all-ones
-    sentinels.  `probe_mint_insert` is the fused resolve: binary-search
-    probe, first-occurrence pid minting and merge-insert in ONE jitted
-    program (one dispatch, one host sync per resolve) — the mint + merge
-    half sits behind a `lax.cond`, so the all-found steady state of
-    propagation never pays for the sort.  The old columns are donated
-    back to XLA on accelerators.  The staged three-step path
-    (`_probe_step` -> `_resolve_step` -> `_merge_step`) is kept as the
-    bit-parity reference.  Results are bit-identical to
-    `SigStore.get_or_assign` (same probe keys -> same pids, same
-    next_pid), so device and host propagation agree bit-for-bit.  The
-    host `SigStore` is re-materialized lazily (`to_host`) only when the
-    store is extracted — between updates the columns never leave the
-    device.
+    sentinels.  `probe_mint_insert` resolves a batch: a sort-free
+    binary-search probe on device, first-occurrence minting of the
+    misses on host (`sig_store.mint_novel`, the host store's own rule,
+    over the few missing keys), and a sort-free merge by rank of the
+    novel keys on device, dispatched only when something is novel.  The
+    old columns are donated back to XLA on accelerators.  Results are
+    bit-identical to `SigStore.get_or_assign` (same probe keys -> same
+    pids, same next_pid), so device and host propagation agree
+    bit-for-bit.  The host `SigStore` is re-materialized lazily
+    (`to_host`) only when the store is extracted — between updates the
+    columns never leave the device.
 
   * `resident_level_resolve` — the cross-level maintenance residency
-    program: fold + probe + mint + changed-mask for one propagation
-    level fused into a single dispatch, returning only two scalars
-    (n_novel, n_changed) to the host in the steady state; the pid deltas
-    cross back only for levels where something actually changed, and the
-    merge-insert runs as a separate dispatch only when something was
-    novel.  `BisimMaintainer._propagate` drives it level by level, so a
-    k-level propagation where nothing changes costs k dispatches and k
-    scalar syncs — no N-sized transfer at all.
+    program: fold + probe + changed count for one propagation level
+    fused into a single dispatch, returning one scalar to the host in
+    the steady state; the probe lanes cross back only for levels where
+    something actually changed, where the misses are minted and merged.
+    `resident_levels_resolve` runs every level in one dispatch while
+    nothing changes.  No device program of maintenance holds a sort, so
+    each new frontier bucket compiles in seconds on the TPU, where a
+    sort of 2^16+ elements takes tens of seconds to compile.
 
 Keys are kept as two u32 lanes (not fused u64) because JAX runs without
 x64 and TPU vector units are 32-bit; lexicographic (hi, lo) order equals
@@ -62,7 +60,7 @@ import numpy as np
 
 from . import hashes_np
 from . import signatures as sig
-from .sig_store import SigStore, fuse_key, split_key
+from .sig_store import SigStore, fuse_key, mint_novel, split_key
 from ..obs import tracer as obs
 
 _I32_MAX = np.iinfo(np.int32).max
@@ -73,6 +71,8 @@ _SENT = jnp.uint32(0xFFFFFFFF)
 # bounds the number of compiled programs for tiny batches.  Callers that
 # care about padding waste on small batches can pass a smaller floor.
 BUCKET_FLOOR = 8
+# Floor of the novel-key batch a store merge takes.
+_NOVEL_FLOOR = 1 << 12
 
 
 def bucket(n: int, floor: "int | None" = None) -> int:
@@ -319,214 +319,140 @@ def _probe_core(khi, klo, kpid, qhi, qlo, count, size):
     return valid, found, out
 
 
-def _mint_plan(qhi, qlo, valid, found, out, next_pid):
-    """Shared mint plan: first-occurrence pid assignment for the missing
-    probe keys.  Mirrors `SigStore.get_or_assign` exactly: found keys
-    keep their stored pid; novel keys mint ``next_pid + rank`` where rank
-    is the order of first occurrence in the probe batch.  Returns
-    everything the merge step needs so nothing is recomputed on insert.
-    """
-    p = qhi.shape[0]
-    miss = jnp.logical_and(valid, ~found)
-    # group the missing keys (sentinel-masked so found/padding sort last);
-    # miss-before-masked then position as tiebreaks, so each group head is
-    # the key's first occurrence even for a genuine all-ones key sharing
-    # the sentinel value with masked lanes (the same defense the merge
-    # step applies with its real-before-sentinel flag)
-    mh = jnp.where(miss, qhi, _SENT)
-    ml = jnp.where(miss, qlo, _SENT)
-    pos = jnp.arange(p, dtype=jnp.int32)
-    order = jnp.lexsort((pos, (~miss).astype(jnp.uint32), ml, mh))
-    sh = mh[order]
-    sl = ml[order]
-    sidx = pos[order]
-    smiss = miss[order]
-    head = jnp.concatenate([
-        jnp.ones((1,), bool), (sh[1:] != sh[:-1]) | (sl[1:] != sl[:-1])])
-    is_first = head & smiss
-    gid = (jnp.cumsum(head) - 1).astype(jnp.int32)
-    # appearance rank of each novel head = #novel heads at earlier probe
-    # positions (matches the numpy store's double-argsort of `first`)
-    head_pos = jnp.where(is_first, sidx, jnp.int32(p))
-    rank = jnp.argsort(jnp.argsort(head_pos)).astype(jnp.int32)
-    app = jax.ops.segment_max(jnp.where(is_first, rank, 0), gid,
-                              num_segments=p)
-    minted = next_pid + app[gid]
-    out = out.at[sidx].set(jnp.where(smiss, minted, out[sidx]))
-    n_novel = jnp.sum(is_first).astype(jnp.int32)
-    return out, n_novel, sh, sl, minted, is_first
-
-
 @jax.jit
 def _probe_step(khi, klo, kpid, qhi, qlo, count, size):
-    """Probe-only program (staged reference path): binary search +
-    gather, no sort.  Kept as the bit-parity oracle for the fused
-    `probe_mint_insert` program below."""
-    valid, found, out = _probe_core(khi, klo, kpid, qhi, qlo, count, size)
-    n_miss = jnp.sum(valid & ~found).astype(jnp.int32)
-    return out, n_miss
+    """The store probe: binary search + gather, no sort.  Returns the
+    stored pid where found, -1 elsewhere."""
+    return _probe_core(khi, klo, kpid, qhi, qlo, count, size)[2]
 
 
-@jax.jit
-def _resolve_step(khi, klo, kpid, qhi, qlo, count, size, next_pid):
-    """Probe + mint plan (staged reference path): one program per
-    (capacity, probe) bucket pair."""
-    valid, found, out = _probe_core(khi, klo, kpid, qhi, qlo, count, size)
-    return _mint_plan(qhi, qlo, valid, found, out, next_pid)
-
-
-def _merge_step_impl(khi, klo, kpid, sh, sl, minted, is_first, size, *,
+def _merge_step_impl(khi, klo, kpid, nhi, nlo, npid, n_novel, size, *,
                      new_cap: int):
-    """Merge the minted novel keys into the sorted columns; re-bucket to
-    `new_cap`.  The old columns are donated (see `_merge_step`), so the
-    store keeps a constant number of live buffers on accelerators."""
+    """Merge `n_novel` sorted novel keys (the leading lanes of nhi/nlo/
+    npid; all-ones sentinels past them) into the sorted store columns;
+    re-bucket to `new_cap`.
+
+    Both inputs are ordered, so the merge is by rank, with no sort: a
+    store key lands at its index plus the novel keys below it, a novel
+    key at its own index plus the store keys below it (the two sets are
+    disjoint: a novel key is by definition missing from S).  Binary
+    searches and one scatter per column; the padding sentinels never
+    count as "below" a real key, so a genuine all-ones key keeps its
+    pid.  Sort-free also means cheap to compile for every probe bucket —
+    a TPU sort of a multi-million-entry store compiles for minutes."""
     cap = khi.shape[0]
-    p = sh.shape[0]
-    ch = jnp.concatenate([khi, jnp.where(is_first, sh, _SENT)])
-    cl = jnp.concatenate([klo, jnp.where(is_first, sl, _SENT)])
-    cp = jnp.concatenate([kpid, jnp.where(is_first, minted, 0)])
-    # real-before-sentinel tiebreak: a genuine all-ones key must beat the
-    # padding sentinels, or its pid would be sliced away below
-    pad = jnp.concatenate([
-        (jnp.arange(cap, dtype=jnp.int32) >= size), ~is_first,
-    ]).astype(jnp.uint32)
-    order = jnp.lexsort((pad, cl, ch))
-    ch, cl, cp = ch[order], cl[order], cp[order]
-    if new_cap <= cap + p:
-        return ch[:new_cap], cl[:new_cap], cp[:new_cap]
-    extra = new_cap - (cap + p)
-    return (jnp.concatenate([ch, jnp.full(extra, _SENT)]),
-            jnp.concatenate([cl, jnp.full(extra, _SENT)]),
-            jnp.concatenate([cp, jnp.zeros(extra, jnp.int32)]))
+    p = nhi.shape[0]
+    novel_pos = jnp.arange(p, dtype=jnp.int32)
+    dest_new = jnp.where(
+        novel_pos < n_novel,
+        novel_pos + jnp.minimum(_searchsorted_pairs(khi, klo, nhi, nlo),
+                                size),
+        new_cap)
+    store_pos = jnp.arange(cap, dtype=jnp.int32)
+    dest_old = jnp.where(
+        store_pos < size,
+        store_pos + jnp.minimum(_searchsorted_pairs(nhi, nlo, khi, klo),
+                                n_novel),
+        new_cap)
+
+    def merged(fill, old, new):
+        out = jnp.full((new_cap,), fill, old.dtype)
+        out = out.at[dest_old].set(old, mode="drop")
+        return out.at[dest_new].set(new, mode="drop")
+
+    return (merged(_SENT, khi, nhi), merged(_SENT, klo, nlo),
+            merged(0, kpid, npid))
 
 
-_merge_step_jit = None
+_merge_step_jits: dict = {}
 
 
-def _merge_step(*args, new_cap: int):
-    """Jit `_merge_step_impl` lazily: donation is decided per backend (CPU
-    ignores it and warns), mirroring `partition._bisim_step`."""
-    global _merge_step_jit
-    if _merge_step_jit is None:
-        donate = () if jax.default_backend() == "cpu" else (0, 1, 2)
-        _merge_step_jit = jax.jit(
+def _merge_step(khi, klo, kpid, *rest, new_cap: int):
+    """Jit `_merge_step_impl` lazily, donating the old columns where XLA
+    can reuse them in place: on accelerators (CPU ignores donation and
+    warns), and only at an unchanged capacity (a regrown store cannot
+    alias its old buffers).  Deciding at the first call keeps the backend
+    query out of import time, as `partition._bisim_step` does."""
+    donate = jax.default_backend() != "cpu" and new_cap == khi.shape[0]
+    fn = _merge_step_jits.get(donate)
+    if fn is None:
+        fn = _merge_step_jits[donate] = jax.jit(
             _merge_step_impl, static_argnames=("new_cap",),
-            donate_argnums=donate)
-    return _merge_step_jit(*args, new_cap=new_cap)
+            donate_argnums=(0, 1, 2) if donate else ())
+    return fn(khi, klo, kpid, *rest, new_cap=new_cap)
 
 
-def _pad_columns(khi, klo, kpid, new_cap: int):
-    """Grow the sorted columns to `new_cap` without touching content."""
-    cap = khi.shape[0]
-    if new_cap == cap:
-        return khi, klo, kpid
-    extra = new_cap - cap
-    return (jnp.concatenate([khi, jnp.full(extra, _SENT)]),
-            jnp.concatenate([klo, jnp.full(extra, _SENT)]),
-            jnp.concatenate([kpid, jnp.zeros(extra, jnp.int32)]))
+def _mint_misses(dstore, out: np.ndarray, qhi: np.ndarray, qlo: np.ndarray,
+                 next_pid: int) -> int:
+    """The host half of a resolve: the probe lanes the device store
+    missed (``out < 0``) get pids minted exactly as
+    `SigStore.get_or_assign` mints them (`mint_novel`: one per distinct
+    key, in order of first occurrence), written into `out` in place; the
+    novel keys are merged into the device columns.  Returns next_pid'.
+
+    Minting is a sort of the missing keys, which stays on the host: the
+    device programs of maintenance hold no sort at all, so each new
+    frontier bucket compiles in seconds."""
+    miss = out < 0
+    if not miss.any():
+        return next_pid
+    ukeys, new_pids, inv = mint_novel(fuse_key(qhi[miss], qlo[miss]),
+                                      next_pid)
+    if next_pid + ukeys.shape[0] > _I32_MAX:
+        raise OverflowError(
+            "device store pid space exceeded int32; rebuild to "
+            "re-densify pids")
+    out[miss] = new_pids[inv]
+    dstore.insert_sorted(ukeys, new_pids)
+    return next_pid + int(ukeys.shape[0])
 
 
-def _probe_mint_insert_impl(khi, klo, kpid, qhi, qlo, count, size,
-                            next_pid, *, new_cap: int):
-    """The fused resolve: probe + mint + merge-insert as ONE program.
-
-    The mint plan and the merge (a multi-key sort) sit behind a
-    `lax.cond` on the miss count, so the all-found steady state executes
-    only the branchless binary search plus a column pad/copy — XLA's
-    conditional runs a single branch.  Any miss implies at least one
-    novel key (a missing key is by definition not in S), so the mint
-    branch never merges an empty batch.
-
-    Returns (out, n_novel, new_khi, new_klo, new_kpid); the new columns
-    are correct in BOTH branches (the no-miss branch passes the old
-    content through, padded to `new_cap`), so the caller rebinds
-    unconditionally — which also keeps donation sound on accelerators.
-    """
-    valid, found, out = _probe_core(khi, klo, kpid, qhi, qlo, count, size)
-    n_miss = jnp.sum(valid & ~found).astype(jnp.int32)
-
-    def with_mint(_):
-        out2, n_novel, sh, sl, minted, is_first = _mint_plan(
-            qhi, qlo, valid, found, out, next_pid)
-        nkhi, nklo, nkpid = _merge_step_impl(
-            khi, klo, kpid, sh, sl, minted, is_first, size,
-            new_cap=new_cap)
-        return out2, n_novel, nkhi, nklo, nkpid
-
-    def no_mint(_):
-        nkhi, nklo, nkpid = _pad_columns(khi, klo, kpid, new_cap)
-        return out, jnp.int32(0), nkhi, nklo, nkpid
-
-    return jax.lax.cond(n_miss > 0, with_mint, no_mint, None)
-
-
-_probe_mint_insert_jit = None
-
-
-def _probe_mint_insert(*args, new_cap: int):
-    """Lazy jit of the fused resolve; donates the store columns on
-    accelerators (the caller always rebinds to the outputs)."""
-    global _probe_mint_insert_jit
-    if _probe_mint_insert_jit is None:
-        donate = () if jax.default_backend() == "cpu" else (0, 1, 2)
-        _probe_mint_insert_jit = jax.jit(
-            _probe_mint_insert_impl, static_argnames=("new_cap",),
-            donate_argnums=donate)
-    return _probe_mint_insert_jit(*args, new_cap=new_cap)
+def _settle_level(dstore, qhi, qlo, out, old_pid, num_sigs: int,
+                  next_pid: int):
+    """A level whose pids changed: pull its probe lanes, mint the misses
+    on host, merge them into the store.  Returns ((pids int64, changed
+    bool, n_changed), next_pid')."""
+    obs.event("maint.sync", what="level_deltas", keys=num_sigs)
+    # whole bucket-padded lanes, trimmed on host: slicing on device
+    # would compile one more program per frontier length
+    out_h, qh, ql = (np.asarray(x)[:num_sigs]
+                     for x in jax.device_get((out, qhi, qlo)))
+    pj = out_h.astype(np.int64)
+    next_pid = _mint_misses(dstore, pj, qh, ql, next_pid)
+    changed = pj != np.asarray(old_pid)
+    return (pj, changed, int(changed.sum())), next_pid
 
 
 @jax.jit
 def _level_resident_step(p0, lab, tgt, bounds, e_count, khi, klo, kpid,
-                         size, next_pid, old_pid, count):
+                         size, old_pid, count):
     """One maintenance level as ONE program: presorted/deduplicated fold
-    (hash lanes + segment wrap-sum + final mix), store probe, cond-gated
-    mint plan, and the changed-vs-old mask — so the steady state of
-    propagation transfers exactly two scalars per level.
-
-    The merge-insert is NOT part of this program: novelty is rare in
-    propagation, and folding the merge in would force a store-capacity
-    copy per level on backends that ignore donation.  The caller runs
-    `_merge_step` as a second dispatch only when n_novel > 0, feeding it
-    the (sh, sl, minted, is_first) plan returned here.
-    """
+    (hash lanes + segment wrap-sum + final mix), store probe and the
+    changed count — so the steady state of propagation transfers one
+    scalar per level.  A missing key counts as changed: the pid it will
+    be minted is at least next_pid, above every pid in use."""
     nb = p0.shape[0]
     qhi, qlo = sig.frontier_signature_hashes_presorted(
         p0, lab, tgt, bounds, e_count, num_sigs=nb)
-    valid, found, out = _probe_core(khi, klo, kpid, qhi, qlo, count, size)
-    n_miss = jnp.sum(valid & ~found).astype(jnp.int32)
-    p = qhi.shape[0]
-
-    def with_mint(_):
-        return _mint_plan(qhi, qlo, valid, found, out, next_pid)
-
-    def no_mint(_):
-        return (out, jnp.int32(0), jnp.full((p,), _SENT),
-                jnp.full((p,), _SENT), jnp.zeros((p,), jnp.int32),
-                jnp.zeros((p,), bool))
-
-    out, n_novel, sh, sl, minted, is_first = jax.lax.cond(
-        n_miss > 0, with_mint, no_mint, None)
-    changed = valid & (out != old_pid)
-    n_changed = jnp.sum(changed).astype(jnp.int32)
-    return out, n_novel, n_changed, changed, sh, sl, minted, is_first
+    valid, _found, out = _probe_core(khi, klo, kpid, qhi, qlo, count, size)
+    n_changed = jnp.sum(valid & (out != old_pid)).astype(jnp.int32)
+    return qhi, qlo, out, n_changed
 
 
 @jax.jit
 def _levels_resident_step(p0, count, labs, tgts, boundss, es, olds,
-                          stores, sizes, next_pids):
-    """ALL maintenance levels as ONE program (tentpole: one dispatch per
-    k-loop).  Levels unroll at trace time — a `lax.scan` cannot carry the
+                          stores, sizes):
+    """ALL maintenance levels as ONE program (one dispatch per k-loop).
+    Levels unroll at trace time — a `lax.scan` cannot carry the
     per-level store columns, whose capacities differ — but the compiled
     artifact is still a single XLA dispatch whose steady-state sync is
-    the two stacked scalar vectors (n_novel, n_changed per level).
+    the stacked changed counts.
 
     Level j's fold consumes pId_{j-1} of the frontier targets *as
     uploaded before the dispatch*, which is only valid while earlier
     levels changed nothing: the host trusts the results up to and
-    including the FIRST level with a nonzero scalar and re-runs the rest
-    through the per-level ladder.  Rows past that level are garbage and
-    ignored (computing them costs a few fold+probe passes, which the
-    per-level path would have spent anyway).
+    including the FIRST level with a nonzero count and re-runs the rest
+    through the per-level ladder.
 
     `labs`/`boundss`/`es` are either shared across levels (1-D / scalar:
     the multiset route, where the fold constants are frontier-only) or
@@ -534,7 +460,7 @@ def _levels_resident_step(p0, count, labs, tgts, boundss, es, olds,
     reorders each level differently); the discrimination is static.
     """
     k = tgts.shape[0]
-    n_novels, n_changeds, per_level = [], [], []
+    n_changeds, per_level = [], []
     for j in range(k):
         lab = labs if labs.ndim == 1 else labs[j]
         bounds = boundss if boundss.ndim == 1 else boundss[j]
@@ -543,27 +469,12 @@ def _levels_resident_step(p0, count, labs, tgts, boundss, es, olds,
         nb = p0.shape[0]
         qhi, qlo = sig.frontier_signature_hashes_presorted(
             p0, lab, tgts[j], bounds, e, num_sigs=nb)
-        valid, found, out = _probe_core(khi, klo, kpid, qhi, qlo, count,
-                                        sizes[j])
-        n_miss = jnp.sum(valid & ~found).astype(jnp.int32)
-        p = qhi.shape[0]
-
-        def with_mint(_, qhi=qhi, qlo=qlo, valid=valid, found=found,
-                      out=out, npid=next_pids[j]):
-            return _mint_plan(qhi, qlo, valid, found, out, npid)
-
-        def no_mint(_, out=out, p=p):
-            return (out, jnp.int32(0), jnp.full((p,), _SENT),
-                    jnp.full((p,), _SENT), jnp.zeros((p,), jnp.int32),
-                    jnp.zeros((p,), bool))
-
-        out, n_novel, sh, sl, minted, is_first = jax.lax.cond(
-            n_miss > 0, with_mint, no_mint, None)
-        changed = valid & (out != olds[j])
-        n_novels.append(n_novel)
-        n_changeds.append(jnp.sum(changed).astype(jnp.int32))
-        per_level.append((out, changed, sh, sl, minted, is_first))
-    return jnp.stack(n_novels), jnp.stack(n_changeds), tuple(per_level)
+        valid, _found, out = _probe_core(khi, klo, kpid, qhi, qlo, count,
+                                         sizes[j])
+        n_changeds.append(jnp.sum(valid & (out != olds[j]))
+                          .astype(jnp.int32))
+        per_level.append((qhi, qlo, out))
+    return jnp.stack(n_changeds), tuple(per_level)
 
 
 def resident_levels_resolve(dstores, pid0_vals, seg, elabel, tgts,
@@ -586,14 +497,14 @@ def resident_levels_resolve(dstores, pid0_vals, seg, elabel, tgts,
       * dirty   — None when every level is clean, else the per-level
         resident-result triple ``(pj int64, changed bool, n_changed)``
         for level ``nclean + 1``, whose inputs were still valid; its
-        store merge (if anything was novel) has already been applied;
+        novel keys are already minted and merged into its store;
       * next_pid_d — the (possibly advanced) next_pid of that dirty
         level, or None when dirty is None.
 
     Levels past the first dirty one must be recomputed by the caller
     (their uploaded target pids were stale the moment something
     changed).  A no-change propagation costs exactly ONE dispatch and
-    ONE two-vector scalar sync for the whole k-loop.
+    ONE k-vector scalar sync for the whole k-loop.
     """
     k = len(tgts)
     e = int(np.asarray(elabel).shape[0])
@@ -650,55 +561,35 @@ def resident_levels_resolve(dstores, pid0_vals, seg, elabel, tgts,
                                                              copy=False)
     obs.event("maint.dispatch", what="levels_resident", keys=num_sigs,
               levels=k)
-    novs_d, nchs_d, per_level = _levels_resident_step(
+    nchs_d, per_level = _levels_resident_step(
         p0_dev, np.int32(num_sigs), labs, tgt_stack, boundss, es,
         old_stack, tuple((d.khi, d.klo, d.kpid) for d in dstores),
-        np.asarray([d.size for d in dstores], np.int32),
-        np.asarray(next_pids, np.int32))
-    # THE steady-state sync: two k-vectors of scalars for the whole loop
+        np.asarray([d.size for d in dstores], np.int32))
+    # THE steady-state sync: one k-vector of scalars for the whole loop
     obs.event("maint.sync", what="levels_scalars", keys=num_sigs,
               levels=k)
-    novs, nchs = (np.asarray(x) for x in jax.device_get((novs_d, nchs_d)))
-    dirty_lvls = np.flatnonzero((novs > 0) | (nchs > 0))
+    dirty_lvls = np.flatnonzero(np.asarray(jax.device_get(nchs_d)) > 0)
     if dirty_lvls.size == 0:
         return k, None, None
     d = int(dirty_lvls[0])
-    out, changed, sh, sl, minted, is_first = per_level[d]
-    n_novel = int(novs[d])
-    next_pid_d = int(next_pids[d])
-    if n_novel:
-        if next_pid_d + n_novel > _I32_MAX:
-            raise OverflowError(
-                "device store pid space exceeded int32; rebuild to "
-                "re-densify pids")
-        dstore = dstores[d]
-        new_size = dstore.size + n_novel
-        obs.event("maint.dispatch", what="merge_insert", minted=n_novel)
-        dstore.khi, dstore.klo, dstore.kpid = _merge_step(
-            dstore.khi, dstore.klo, dstore.kpid, sh, sl, minted, is_first,
-            jnp.int32(dstore.size), new_cap=bucket(new_size))
-        dstore.size = new_size
-        dstore._host = None
-        next_pid_d += n_novel
-    n_changed = int(nchs[d])
-    obs.event("maint.sync", what="level_deltas", changed=n_changed)
-    out_h, changed_h = jax.device_get((out[:num_sigs],
-                                       changed[:num_sigs]))
-    return d, (np.asarray(out_h).astype(np.int64), np.asarray(changed_h),
-               n_changed), next_pid_d
+    qhi, qlo, out = per_level[d]
+    dirty, next_pid_d = _settle_level(dstores[d], qhi, qlo, out, olds[d],
+                                      num_sigs, int(next_pids[d]))
+    return d, dirty, next_pid_d
 
 
 def resident_level_resolve(dstore, pid0_vals, seg, elabel, pid_tgt,
                            num_sigs: int, old_pid, next_pid: int, *,
                            dedup: bool = True, bounds=None,
                            cache: "dict | None" = None, cache_key=None):
-    """Fold + resolve + changed-mask for one propagation level in one
-    dispatch (tentpole residency path).
+    """Fold + probe + changed count for one propagation level in one
+    dispatch (the per-level residency path).
 
     Bit-identical to `frontier_fold` + `SigStore.get_or_assign` + the
     host ``old != new`` comparison: the set-semantics dedup runs on host
-    exactly as the host path's lexsort would, and every device op is the
-    same integer arithmetic.  Returns
+    exactly as the host path's lexsort would, every device op is the
+    same integer arithmetic, and misses are minted by the same
+    `mint_novel`.  Returns
 
         (pids int64 [num_sigs] | None, changed bool [num_sigs] | None,
          n_changed, next_pid')
@@ -740,35 +631,17 @@ def resident_level_resolve(dstore, pid0_vals, seg, elabel, pid_tgt,
     old_p = np.zeros(nb, np.int32)
     old_p[:num_sigs] = np.asarray(old_pid).astype(np.int32, copy=False)
     obs.event("maint.dispatch", what="level_resident", keys=num_sigs)
-    out, n_novel_d, n_changed_d, changed, sh, sl, minted, is_first = \
-        _level_resident_step(
-            p0_dev, lab_dev, jnp.asarray(tgt_p), bounds_dev, jnp.int32(e),
-            dstore.khi, dstore.klo, dstore.kpid, jnp.int32(dstore.size),
-            jnp.int32(next_pid), jnp.asarray(old_p), jnp.int32(num_sigs))
-    # THE steady-state sync: two scalars per level
-    obs.event("maint.sync", what="level_scalars", keys=num_sigs)
-    n_novel, n_changed = (int(x) for x in
-                          jax.device_get((n_novel_d, n_changed_d)))
-    if n_novel:
-        if next_pid + n_novel > _I32_MAX:
-            raise OverflowError(
-                "device store pid space exceeded int32; rebuild to "
-                "re-densify pids")
-        new_size = dstore.size + n_novel
-        obs.event("maint.dispatch", what="merge_insert", minted=n_novel)
-        dstore.khi, dstore.klo, dstore.kpid = _merge_step(
-            dstore.khi, dstore.klo, dstore.kpid, sh, sl, minted, is_first,
-            jnp.int32(dstore.size), new_cap=bucket(new_size))
-        dstore.size = new_size
-        dstore._host = None
-    next_pid += n_novel
-    if n_changed == 0:
+    qhi, qlo, out, n_changed_d = _level_resident_step(
+        p0_dev, lab_dev, jnp.asarray(tgt_p), bounds_dev, jnp.int32(e),
+        dstore.khi, dstore.klo, dstore.kpid, jnp.int32(dstore.size),
+        jnp.asarray(old_p), jnp.int32(num_sigs))
+    # THE steady-state sync: one scalar per level
+    obs.event("maint.sync", what="level_scalar", keys=num_sigs)
+    if int(n_changed_d) == 0:
         return None, None, 0, next_pid
-    obs.event("maint.sync", what="level_deltas", changed=n_changed)
-    out_h, changed_h = jax.device_get(
-        (out[:num_sigs], changed[:num_sigs]))
-    return (np.asarray(out_h).astype(np.int64), np.asarray(changed_h),
-            n_changed, next_pid)
+    (pj, changed, n_changed), next_pid = _settle_level(
+        dstore, qhi, qlo, out, old_pid, num_sigs, next_pid)
+    return pj, changed, n_changed, next_pid
 
 
 class DeviceSigStore:
@@ -809,21 +682,16 @@ class DeviceSigStore:
     # ------------------------------------------------------------- resolve
     def probe_mint_insert(self, qhi, qlo, count: int,
                           next_pid: int) -> tuple[np.ndarray, int]:
-        """The fused resolve primitive: probe + mint + merge-insert in ONE
-        jitted program, ONE dispatch and ONE device->host sync per call.
+        """Resolve probe keys: device probe, host minting of the misses,
+        device merge-insert of the novel keys (only when something is
+        novel) — one probe dispatch and one sync per call.
 
         `qhi`/`qlo` may be device arrays straight out of `frontier_fold`
-        (no host round-trip) or bucket-padded numpy arrays; only the
-        first `count` entries are real probes.  Returns (pids int64
-        [count], next_pid') — bit-identical to `SigStore.get_or_assign`
-        on the fused keys, and to the staged
-        `_probe_step`/`_resolve_step`/`_merge_step` path (asserted by
+        (no host round-trip before the probe) or bucket-padded numpy
+        arrays; only the first `count` entries are real probes.  Returns
+        (pids int64 [count], next_pid') — bit-identical to
+        `SigStore.get_or_assign` on the fused keys (asserted by
         tests/test_fused_build.py).
-
-        The target capacity is computed on host from worst-case growth
-        (every probe novel), so regrowth stays capacity-bucketed: the
-        program cache holds O(log^2) entries over (capacity, probe,
-        new-capacity) buckets per session.
         """
         if next_pid + count > _I32_MAX:
             raise OverflowError(
@@ -831,25 +699,44 @@ class DeviceSigStore:
                 "re-densify pids")
         qhi = jnp.asarray(qhi)
         qlo = jnp.asarray(qlo)
+        with obs.span("store.resolve_device", keys=count) as sp:
+            obs.event("maint.dispatch", what="probe", keys=count)
+            out = _probe_step(
+                self.khi, self.klo, self.kpid, qhi, qlo, jnp.int32(count),
+                jnp.int32(self.size))
+            obs.event("maint.sync", what="probe", keys=count)
+            out_h, qh, ql = (np.asarray(x)[:count]
+                             for x in jax.device_get((out, qhi, qlo)))
+            out_h = out_h.astype(np.int64)
+            nxt = _mint_misses(self, out_h, qh, ql, next_pid)
+            sp.set(minted=nxt - next_pid)
+        return out_h, nxt
+
+    def insert_sorted(self, ukeys: np.ndarray, pids: np.ndarray) -> None:
+        """Merge sorted, distinct keys that are not in the store (with
+        their pids) into the device columns: one sort-free merge
+        dispatch.  The capacity only grows, in power-of-two buckets; the
+        novel batch is bucketed from `_NOVEL_FLOOR` up, so the store's
+        merge compiles a handful of shapes per session (its cost is the
+        store's length, not the batch's)."""
+        n = int(ukeys.shape[0])
+        p = bucket(n, _NOVEL_FLOOR)
+        hi, lo = split_key(ukeys)
+        nhi = np.full(p, 0xFFFFFFFF, np.uint32)
+        nlo = np.full(p, 0xFFFFFFFF, np.uint32)
+        npid = np.zeros(p, np.int32)
+        nhi[:n] = hi
+        nlo[:n] = lo
+        npid[:n] = pids
+        new_size = self.size + n
         cap = self.khi.shape[0]
-        new_cap = cap if self.size + count <= cap \
-            else bucket(self.size + count)
-        with obs.span("store.resolve_device", keys=count, fused=True) as sp:
-            obs.event("maint.dispatch", what="probe_mint_insert",
-                      keys=count)
-            out, n_novel, self.khi, self.klo, self.kpid = \
-                _probe_mint_insert(
-                    self.khi, self.klo, self.kpid, qhi, qlo,
-                    jnp.int32(count), jnp.int32(self.size),
-                    jnp.int32(next_pid), new_cap=new_cap)
-            obs.event("maint.sync", what="probe_mint_insert", keys=count)
-            out_h, n = jax.device_get((out[:count], n_novel))
-            n = int(n)
-            sp.set(minted=n)
-            if n:
-                self.size += n
-                self._host = None  # mirrored back lazily on extraction
-        return np.asarray(out_h).astype(np.int64), next_pid + n
+        new_cap = cap if new_size <= cap else bucket(new_size)
+        obs.event("maint.dispatch", what="merge_insert", minted=n)
+        self.khi, self.klo, self.kpid = _merge_step(
+            self.khi, self.klo, self.kpid, nhi, nlo, npid, np.int32(n),
+            np.int32(self.size), new_cap=new_cap)
+        self.size = new_size
+        self._host = None  # mirrored back lazily on extraction
 
     def get_or_assign_pairs(self, qhi, qlo, count: int,
                             next_pid: int) -> tuple[np.ndarray, int]:

@@ -231,6 +231,14 @@ def make_flat_mesh(devices=None):
     return jax.make_mesh((len(devices),), ("devices",), devices=devices)
 
 
+def place_sharded(sg: ShardedGraph, mesh, axis=("devices",)) -> dict:
+    """The build's device inputs: every per-node and per-edge column of
+    `sg`, split along `axis` so that device d holds its own slice."""
+    sharding = jax.sharding.NamedSharding(mesh, P(axis))
+    return {name: jax.device_put(jnp.asarray(getattr(sg, name)), sharding)
+            for name in ("pid0", "src_local", "dst", "elabel", "valid")}
+
+
 def build_bisim_distributed(
         graph: Graph, k: int, *, mesh=None, axis=("devices",),
         mode: str = "sorted", ranking: str = "allgather",
@@ -253,13 +261,8 @@ def build_bisim_distributed(
     else:
         capacity = max(int(np.ceil(n_loc / d * capacity_factor)), 8)
 
-    sharding = jax.sharding.NamedSharding(mesh, P(axis))
-    dev = lambda x: jax.device_put(jnp.asarray(x), sharding)
-    pid0 = dev(sg.pid0)
-    src_local = dev(sg.src_local)
-    dst = dev(sg.dst)
-    elabel = dev(sg.elabel)
-    valid = dev(sg.valid)
+    pid0, src_local, dst, elabel, valid = place_sharded(
+        sg, mesh, axis).values()
 
     pad_parts = 1 if sg.has_padding else 0
     counts = [sg.num_pid0 - pad_parts]

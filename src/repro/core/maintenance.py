@@ -77,7 +77,7 @@ import numpy as np
 
 from repro.graph.storage import Graph
 from . import hashes_np
-from .faults import InjectedCrash, fault_point
+from .faults import TransientIOError, fault_point
 from .partition import BisimResult, bisim_step, build_bisim
 from .sig_store import SigStore, fuse_key, label_key
 from ..obs import tracer as obs
@@ -676,10 +676,11 @@ class BisimMaintainer:
     ``device=True`` asks the backend for device-resident propagation
     (see the module docstring's contract); backends without the
     capability silently keep the host path, and `self.device` reports
-    which one is active.  A device failure mid-stream (a flaky
-    accelerator, an injected fault) degrades to the bit-identical host
-    path with a warning instead of aborting the stream — `self.device`
-    flips to False and stays there.
+    which one is active.  A transient device fault mid-stream
+    (`TransientIOError`, what the fault layer injects) degrades to the
+    bit-identical host path with a warning instead of aborting the
+    stream — `self.device` flips to False and stays there.  Any other
+    device error propagates.
 
     ``wal=True`` logs every logical update to the backend's write-ahead
     log *before* applying it (classic redo rule), so
@@ -1041,9 +1042,7 @@ class BisimMaintainer:
                 fault_point("device", "level 1")
                 multi = self.backend.propagate_levels_resident(
                     frontier, dedup=dedup)
-            except InjectedCrash:
-                raise
-            except Exception as exc:
+            except TransientIOError as exc:
                 warnings.warn(
                     f"device propagation failed ({exc!r}); degrading "
                     "to the bit-identical host path", RuntimeWarning)
@@ -1093,13 +1092,15 @@ class BisimMaintainer:
                         if resident is None:
                             pj = self.backend.propagate_level_device(
                                 j, frontier, dedup=dedup)
-                    except InjectedCrash:
-                        raise  # a simulated process death is not degradable
-                    except Exception as exc:
-                        # graceful degradation: the host path computes the
-                        # bit-identical partition, so a flaky device demotes
-                        # the stream instead of killing it; the flip is
-                        # permanent for this maintainer (no retry storms)
+                    except TransientIOError as exc:
+                        # graceful degradation from a transient fault (the
+                        # fault layer's `fault_point("device", ...)`): the
+                        # host path computes the bit-identical partition,
+                        # so the stream is demoted instead of killed, for
+                        # good (no retry storms).  Anything else — an XLA
+                        # compile refusal, device OOM, a runtime error, a
+                        # simulated crash — propagates: a device failure
+                        # must never pass as a host-path run.
                         warnings.warn(
                             f"device propagation failed ({exc!r}); degrading "
                             "to the bit-identical host path", RuntimeWarning)

@@ -368,23 +368,32 @@ def partition_blocks(pids: np.ndarray) -> dict:
     return blocks
 
 
+def _pair_counts(a: np.ndarray, b: np.ndarray):
+    """(#distinct a, #distinct b, #distinct (a, b) pairs) of two equal-
+    length labelings, by dense re-ranking and one fused int64 key."""
+    ua, ra = np.unique(np.asarray(a).ravel(), return_inverse=True)
+    ub, rb = np.unique(np.asarray(b).ravel(), return_inverse=True)
+    pairs = np.unique(ra.astype(np.int64) * len(ub) + rb)
+    return len(ua), len(ub), len(pairs)
+
+
 def same_partition(a: np.ndarray, b: np.ndarray) -> bool:
-    """Do two pid labelings induce the same partition (up to renaming)?"""
+    """Do two pid labelings induce the same partition (up to renaming)?
+
+    They do iff the blocks correspond one to one, i.e. iff there are as
+    many distinct (a, b) pairs as distinct a values and as distinct b
+    values — three sorts, so a multi-million-node history checks in a
+    fraction of a second."""
     a = np.asarray(a)
     b = np.asarray(b)
     if a.shape != b.shape:
         return False
-    fwd, bwd = {}, {}
-    for x, y in zip(a.tolist(), b.tolist()):
-        if fwd.setdefault(x, y) != y or bwd.setdefault(y, x) != x:
-            return False
-    return True
+    na, nb, npairs = _pair_counts(a, b)
+    return na == nb == npairs
 
 
 def refines(fine: np.ndarray, coarse: np.ndarray) -> bool:
-    """Is partition `fine` a refinement of `coarse`?"""
-    m = {}
-    for f, c in zip(np.asarray(fine).tolist(), np.asarray(coarse).tolist()):
-        if m.setdefault(f, c) != c:
-            return False
-    return True
+    """Is partition `fine` a refinement of `coarse`?  (Every fine block
+    lies in one coarse block: one distinct pair per fine value.)"""
+    nf, _, npairs = _pair_counts(fine, coarse)
+    return nf == npairs

@@ -46,6 +46,19 @@ def fuse_key(hi, lo) -> np.ndarray:
     return (hi.astype(_U64) << _SHIFT) | lo.astype(_U64)
 
 
+def mint_novel(keys, next_pid: int):
+    """Pids for keys that are missing from a store: one per distinct
+    key, numbered from `next_pid` in order of first occurrence in `keys`
+    (what a sequential dict walk over the frontier would assign).
+    Returns (distinct keys ascending, their pids int64, inverse index
+    of every key into them).  The one minting rule of the host store and
+    the device mirror."""
+    ukeys, first, inv = np.unique(np.asarray(keys, dtype=_U64),
+                                  return_index=True, return_inverse=True)
+    appearance = np.argsort(np.argsort(first, kind="stable"), kind="stable")
+    return ukeys, np.int64(next_pid) + appearance, inv
+
+
 def label_key(labels) -> np.ndarray:
     """Level-0 key: the raw node label in the lo lane (hi lane zero)."""
     return np.asarray(labels).astype(np.uint32, copy=False).astype(_U64)
@@ -153,13 +166,7 @@ class SigStore:
         if found.all():
             return out, next_pid
         miss = ~found
-        mkeys = keys[miss]
-        ukeys, first, inv = np.unique(mkeys, return_index=True,
-                                      return_inverse=True)
-        # rank unique novel keys by first appearance in the probe order
-        appearance = np.argsort(np.argsort(first, kind="stable"),
-                                kind="stable")
-        new_pids = np.int64(next_pid) + appearance
+        ukeys, new_pids, inv = mint_novel(keys[miss], next_pid)
         out[miss] = new_pids[inv]
         merged_keys = np.concatenate([self.keys, ukeys])
         merged_pids = np.concatenate([self.pids, new_pids])

@@ -177,8 +177,7 @@ def frontier_signature_hashes(pid0: jax.Array, seg: jax.Array,
             # fold's in-kernel adjacent-compare dedup (presorted lanes)
             from repro.kernels import sig_fold as kernel_fold
             seg_hi, seg_lo = kernel_fold.frontier_sig_fold(
-                slab, stgt, sseg, sval, num_sigs=num_sigs, dedup=True,
-                presorted=True)
+                slab, stgt, sseg, sval, num_sigs=num_sigs, dedup=True)
             return hash_triple(seg_hi, seg_lo, pid0)
         keep = jnp.concatenate([
             jnp.ones((1,), bool),
@@ -192,8 +191,8 @@ def frontier_signature_hashes(pid0: jax.Array, seg: jax.Array,
         return hash_triple(segment_wrapsum(e_hi, bounds),
                            segment_wrapsum(e_lo, bounds), pid0)
     if use_kernel:
-        # multiset mode on TPU: the whole fold is the Pallas sig_fold's
-        # masked hash + segmented sum (one single-block call)
+        # multiset mode on TPU: the whole fold is the Pallas kernel's
+        # masked hash + segmented sum
         from repro.kernels import sig_fold as kernel_fold
         valid = jnp.arange(elabel.shape[0], dtype=jnp.int32) < count
         seg_hi, seg_lo = kernel_fold.frontier_sig_fold(

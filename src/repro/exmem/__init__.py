@@ -114,9 +114,9 @@ input, not an exception path.  The subsystem's guarantees:
     fault-point into a crash (`InjectedCrash`), a transient
     (`TransientIOError`, retried with bounded backoff by
     `with_retries`), or a torn write (file published with its tail
-    missing — caught later by the checksums).  Device-step failures
-    degrade gracefully: the maintainer warns once and falls back to
-    the bit-identical numpy path.
+    missing — caught later by the checksums).  A transient fault at a
+    device step degrades gracefully: the maintainer warns once and falls
+    back to the bit-identical numpy path.  Other device errors propagate.
 
   Non-guarantees.  fsync durability is only as real as the
     filesystem's; uncommitted WAL tail records are dropped (by design);

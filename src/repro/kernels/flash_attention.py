@@ -14,11 +14,14 @@ keeping the MXU contraction dims at 128.
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from . import interpret_mode
 
 _NEG = -0.7 * float(jnp.finfo(jnp.float32).max)
 
@@ -78,7 +81,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 def flash_attention(q, k, v, *, causal: bool = True, window: int = None,
                     softcap: float = None, scale: float = None,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
+                    interpret: Optional[bool] = None):
     """q: [B, Hq, Sq, D]; k, v: [B, Hkv, Skv, D]; Hq % Hkv == 0."""
     b, hq, sq, d = q.shape
     _, hkv, skv, _ = k.shape
@@ -116,6 +119,6 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = None,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, d), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(qf, kf, vf)
     return out.reshape(b, hq, sq, d)

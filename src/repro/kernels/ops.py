@@ -1,14 +1,15 @@
 """jit'd public wrappers for the Pallas kernels + host-side layout builders.
 
-Kernels are TPU-target (pl.pallas_call + BlockSpec VMEM tiling) and are
-validated on CPU with interpret=True against the oracles in ref.py. The
-model/dry-run paths use XLA-native math by default (`interpret` kernels are
-not lowerable in the CPU dry-run); on real TPU hardware `use_kernel=True`
-switches the hot paths over.
+Kernels are TPU-target (pl.pallas_call + BlockSpec VMEM tiling).  On the
+CPU backend they run interpreted and are validated against the oracles
+in ref.py; on any other backend they compile (`interpret_mode`).  The
+engine paths use XLA-native math by default; ``use_kernel=True`` switches
+the folds over.
 """
 from __future__ import annotations
 
 import functools
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -80,7 +81,7 @@ def blocked_csr_layout(src: np.ndarray, dst: np.ndarray, elabel: np.ndarray,
     "nodes_per_block", "edges_per_block", "num_nodes", "interpret"))
 def sig_fold_from_layout(elabel, dst, local_src, valid, pid_prev, *,
                          nodes_per_block: int, edges_per_block: int,
-                         num_nodes: int, interpret: bool = True):
+                         num_nodes: int, interpret: Optional[bool] = None):
     """Gather pid_prev[dst] then run the sig_fold kernel; trims padding."""
     pid_tgt = pid_prev[dst]
     hi, lo = _sig_fold.sig_fold(

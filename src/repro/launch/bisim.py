@@ -38,6 +38,7 @@ from __future__ import annotations
 import argparse
 import time
 
+from repro.compat import use_compile_cache
 from repro.core import build_bisim, build_bisim_distributed
 from repro.graph import generators as gen
 from repro.graph.storage import Graph
@@ -703,6 +704,7 @@ def _dispatch(args) -> None:
 
 def main() -> None:
     args = build_parser().parse_args()
+    use_compile_cache()
     if not args.trace:
         _dispatch(args)
         return

@@ -15,11 +15,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
-try:
-    shard_map = jax.shard_map
-except AttributeError:  # pragma: no cover
-    from jax.experimental.shard_map import shard_map
-
 
 def quantize_int8(x):
     """Per-tensor symmetric int8 quantization. Returns (q, scale)."""
@@ -48,10 +43,7 @@ def compressed_psum(x, axis: str):
     padded internally). Bytes on the wire: 2 * |x| int8 (+ scales) instead
     of 2 * |x| f32.
     """
-    try:
-        d = jax.lax.axis_size(axis)  # jax >= 0.6
-    except AttributeError:
-        d = jax.lax.psum(1, axis)
+    d = jax.lax.axis_size(axis)
     flat = x.reshape(-1).astype(jnp.float32)
     n = flat.shape[0]
     pad = (-n) % d

@@ -23,6 +23,7 @@ Everything runs with ``io_threads=0`` where determinism of the global
 fault-point sequence matters (single-threaded => stable indices).
 """
 import os
+import warnings
 
 import numpy as np
 import pytest
@@ -472,18 +473,18 @@ def test_wal_requires_backend_support():
 
 # ------------------------------------------------- graceful degradation
 def test_device_failure_falls_back_to_host(tmp_path):
-    """A device-step failure degrades to the bit-identical numpy path
-    with a warning — the update still lands, and the maintainer stays
-    correct afterwards with device propagation off."""
+    """A transient device-step fault degrades to the bit-identical numpy
+    path with a warning — the update still lands, and the maintainer
+    stays correct afterwards with device propagation off."""
     be = OocBackend(_graph(), chunk_edges=32, chunk_nodes=24,
                     workdir=str(tmp_path / "m"), io_threads=0)
     m = BisimMaintainer(be, 2, device=True)
     assert m.device
 
-    def dead_device(*a, **k):
-        raise RuntimeError("device lost")
+    def flaky_device(*a, **k):
+        raise TransientIOError("device step timed out")
 
-    be.propagate_level_device = dead_device
+    be.propagate_level_device = flaky_device
     with pytest.warns(RuntimeWarning, match="degrading"):
         m.add_edges(np.array([0, 1], np.int32), np.array([0, 1], np.int32),
                     np.array([2, 3], np.int32))
@@ -492,3 +493,36 @@ def test_device_failure_falls_back_to_host(tmp_path):
     for j in range(m.k + 1):
         assert same_partition(m.pids[j], ref.pids[j]), j
     be.close()
+
+
+@pytest.mark.parametrize("backend", ["memory", "ooc"])
+def test_device_error_propagates(tmp_path, backend):
+    """Any device error other than a transient fault (an XLA compile
+    refusal, device OOM, a runtime error) propagates out of the update
+    and leaves device propagation on: a failed chip step never passes as
+    a host-path run."""
+    from repro.core import InMemoryBackend
+    if backend == "ooc":
+        be = OocBackend(_graph(), chunk_edges=32, chunk_nodes=24,
+                        workdir=str(tmp_path / "m"), io_threads=0)
+    else:
+        be = InMemoryBackend(_graph())
+    m = BisimMaintainer(be, 2, device=True)
+    assert m.device
+
+    def dead_device(*a, **k):
+        raise RuntimeError("device lost")
+
+    # every rung of the device ladder fails the same way
+    be.propagate_levels_resident = dead_device
+    be.propagate_level_resident = dead_device
+    be.propagate_level_device = dead_device
+    with warnings.catch_warnings(record=True) as caught, \
+            pytest.raises(RuntimeError, match="device lost"):
+        warnings.simplefilter("always")
+        m.add_edges(np.array([0, 1], np.int32), np.array([0, 1], np.int32),
+                    np.array([2, 3], np.int32))
+    assert not [w for w in caught if "degrading" in str(w.message)]
+    assert m.device
+    if backend == "ooc":
+        be.close()

@@ -1,6 +1,6 @@
 """Fused-vs-staged parity and dispatch-count contracts (PR 8).
 
-The fused build (`build_bisim(fused=True)`) and the fused store resolve
+The fused build (`build_bisim(fused=True)`) and the device store resolve
 (`DeviceSigStore.probe_mint_insert`) must be bit-identical to their
 staged references — same pids, same per-iteration counts, same store
 contents — while honouring the one-sync contract the docstrings
@@ -14,7 +14,7 @@ import repro.core.device_maint as dm
 from repro import obs
 from repro.core import partition
 from repro.core.device_maint import DeviceSigStore, bucket
-from repro.core.sig_store import SigStore
+from repro.core.sig_store import SigStore, mint_novel
 from repro.graph import generators
 
 jax = pytest.importorskip("jax")
@@ -104,26 +104,35 @@ def _fresh_pair(entries=()):
 
 
 def _staged_resolve(dev, qhi, qlo, count, next_pid):
-    """Reference ladder: _probe_step -> _resolve_step -> _merge_step."""
-    out, n_miss = dm._probe_step(dev.khi, dev.klo, dev.kpid, qhi, qlo,
-                                 jnp.int32(count), jnp.int32(dev.size))
-    n_miss = int(n_miss)
-    if n_miss == 0:
-        return np.asarray(jax.device_get(out[:count])).astype(np.int64), \
-            next_pid
-    out, n_novel, sh, sl, minted, is_first = dm._resolve_step(
-        dev.khi, dev.klo, dev.kpid, qhi, qlo,
-        jnp.int32(count), jnp.int32(dev.size), jnp.int32(next_pid))
-    n = int(n_novel)
+    """Reference composition of the resolve's three steps, spelled out:
+    the probe program, host minting of the misses (`mint_novel`), and
+    the merge-by-rank program fed the sorted novel keys."""
+    out = dm._probe_step(dev.khi, dev.klo, dev.kpid, qhi, qlo,
+                         jnp.int32(count), jnp.int32(dev.size))
+    out = np.asarray(jax.device_get(out[:count])).astype(np.int64)
+    miss = out < 0
+    if not miss.any():
+        return out, next_pid
+    keys = (qhi[:count].astype(np.uint64) << np.uint64(32)) \
+        | qlo[:count].astype(np.uint64)
+    ukeys, pids, inv = mint_novel(keys[miss], next_pid)
+    out[miss] = pids[inv]
+    n = ukeys.shape[0]
+    p = bucket(n)
+    nhi = np.full(p, 0xFFFFFFFF, np.uint32)
+    nlo = np.full(p, 0xFFFFFFFF, np.uint32)
+    npid = np.zeros(p, np.int32)
+    nhi[:n] = (ukeys >> np.uint64(32)).astype(np.uint32)
+    nlo[:n] = ukeys.astype(np.uint32)
+    npid[:n] = pids
     cap = dev.khi.shape[0]
     new_cap = cap if dev.size + n <= cap else bucket(dev.size + n)
     dev.khi, dev.klo, dev.kpid = dm._merge_step(
-        dev.khi, dev.klo, dev.kpid, sh, sl, minted, is_first,
-        jnp.int32(dev.size), new_cap=new_cap)
+        dev.khi, dev.klo, dev.kpid, nhi, nlo, npid, np.int32(n),
+        np.int32(dev.size), new_cap=new_cap)
     dev.size += n
     dev._host = None
-    return np.asarray(jax.device_get(out[:count])).astype(np.int64), \
-        next_pid + n
+    return out, next_pid + n
 
 
 def _random_probes(rng, count, pool):
