@@ -42,7 +42,7 @@ def run(scale: int = 1, k: int = 10):
             f"final_partitions={res.counts[-1]};"
             f"partition_ratio={res.counts[-1] / g.num_nodes:.4f};"
             f"dispatches={len(t.find_events('build.dispatch'))};"
-            f"sync_count={len(t.find_events('build.sync'))}"))
+            f"sync_count={len(t.find('build.sync'))}"))
     # one tracer across the oocore rows: the BENCH payload gains a
     # "phases" breakdown (where the disk build's time actually goes)
     tracer = obs.Tracer()

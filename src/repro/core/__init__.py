@@ -14,8 +14,9 @@ counts are part of the contract**, not an implementation detail.  Host
 round-trips — not FLOPs — dominate at the frontier/graph sizes the paper
 benchmarks, so each path documents how many XLA program launches and
 device->host transfers it performs, and the tracer (`repro.obs`) emits a
-``build.dispatch``/``build.sync`` or ``maint.dispatch``/``maint.sync``
-event at every one of them so tests and benchmarks can count.
+``build.dispatch`` or ``maint.dispatch`` event at every launch and opens a
+``build.sync`` or ``maint.sync`` span around every transfer, so tests and
+benchmarks can count both and time the host's wait.
 
 * **Fused build** (``build_bisim(fused=True)``, the default without
   per-level stores): the entire k-iteration loop runs inside a single

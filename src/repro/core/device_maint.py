@@ -105,52 +105,55 @@ def _prepare_batch(pid0_vals, seg, elabel, pid_tgt, num_sigs: int, *,
     needs it (device-placed dedup sort or the Pallas kernel route).
     """
     e = int(np.asarray(elabel).shape[0])
-    # 4-byte columns up front: the hash lanes wrap to u32 anyway (bit-
-    # compatible for these non-negative inputs), and both numpy's lexsort
-    # and the transfer move half the bytes
-    seg = np.asarray(seg).astype(np.int32, copy=False)
-    lab = np.asarray(elabel).astype(np.uint32, copy=False)
-    tgt = np.asarray(pid_tgt).astype(np.uint32, copy=False)
-    if bounds is None and e and (np.diff(seg) < 0).any():
-        # the gathers emit edges in (sorted) frontier order; the device
-        # segment combine (segment_wrapsum) relies on it.  A caller
-        # passing `bounds` asserts the grouping itself.
-        raise ValueError("frontier_fold requires ascending seg ids")
-    nb = bucket(num_sigs)
-    if device_sort is None:
-        # XLA CPU's comparator sort is several times slower than numpy's
-        # lexsort; on accelerators the sort belongs in the program
-        device_sort = jax.default_backend() != "cpu"
-    if dedup and not device_sort:
-        # host dedup: the numpy path's exact lexsort + boundary mask,
-        # compressing the batch before it ever crosses to the device
-        order = np.lexsort((tgt, lab, seg))
-        sseg, slab, stgt = seg[order], lab[order], tgt[order]
-        keep = np.ones(e, dtype=bool)
-        keep[1:] = ((sseg[1:] != sseg[:-1]) | (slab[1:] != slab[:-1])
-                    | (stgt[1:] != stgt[:-1]))
-        seg, lab, tgt = sseg[keep], slab[keep], stgt[keep]
-        e = int(seg.shape[0])
-        bounds = None  # boundaries moved; recompute below
-        dedup = False
-    if bounds is None:
-        bounds = np.searchsorted(seg, np.arange(num_sigs + 1))
-    eb = bucket(e)
-    lab_p = np.empty(eb, np.uint32)
-    lab_p[:e] = lab
-    lab_p[e:] = 0
-    tgt_p = np.empty(eb, np.uint32)
-    tgt_p[:e] = tgt
-    tgt_p[e:] = 0
-    p0 = np.zeros(nb, np.uint32)
-    p0[:num_sigs] = np.asarray(pid0_vals).astype(np.uint32)
-    bounds_p = np.full(nb + 1, e, np.int32)  # empty padding segments
-    bounds_p[: num_sigs + 1] = bounds
-    seg_p = None
-    if dedup:
-        seg_p = np.full(eb, nb, np.int32)    # >= num_sigs: sorts last, and
-        seg_p[:e] = seg                      # falls out of the segment sum
-    return p0, lab_p, tgt_p, bounds_p, seg_p, e, dedup
+    with obs.span("maint.prepare", edges=e, dedup=dedup):
+        # 4-byte columns up front: the hash lanes wrap to u32 anyway
+        # (bit-compatible for these non-negative inputs), and both
+        # numpy's lexsort and the transfer move half the bytes
+        seg = np.asarray(seg).astype(np.int32, copy=False)
+        lab = np.asarray(elabel).astype(np.uint32, copy=False)
+        tgt = np.asarray(pid_tgt).astype(np.uint32, copy=False)
+        if bounds is None and e and (np.diff(seg) < 0).any():
+            # the gathers emit edges in (sorted) frontier order; the
+            # device segment combine (segment_wrapsum) relies on it.  A
+            # caller passing `bounds` asserts the grouping itself.
+            raise ValueError("frontier_fold requires ascending seg ids")
+        nb = bucket(num_sigs)
+        if device_sort is None:
+            # XLA CPU's comparator sort is several times slower than
+            # numpy's lexsort; on accelerators the sort belongs in the
+            # program
+            device_sort = jax.default_backend() != "cpu"
+        if dedup and not device_sort:
+            # host dedup: the numpy path's exact lexsort + boundary mask,
+            # compressing the batch before it ever crosses to the device
+            order = np.lexsort((tgt, lab, seg))
+            sseg, slab, stgt = seg[order], lab[order], tgt[order]
+            keep = np.ones(e, dtype=bool)
+            keep[1:] = ((sseg[1:] != sseg[:-1]) | (slab[1:] != slab[:-1])
+                        | (stgt[1:] != stgt[:-1]))
+            seg, lab, tgt = sseg[keep], slab[keep], stgt[keep]
+            e = int(seg.shape[0])
+            bounds = None  # boundaries moved; recompute below
+            dedup = False
+        if bounds is None:
+            bounds = np.searchsorted(seg, np.arange(num_sigs + 1))
+        eb = bucket(e)
+        lab_p = np.empty(eb, np.uint32)
+        lab_p[:e] = lab
+        lab_p[e:] = 0
+        tgt_p = np.empty(eb, np.uint32)
+        tgt_p[:e] = tgt
+        tgt_p[e:] = 0
+        p0 = np.zeros(nb, np.uint32)
+        p0[:num_sigs] = np.asarray(pid0_vals).astype(np.uint32)
+        bounds_p = np.full(nb + 1, e, np.int32)  # empty padding segments
+        bounds_p[: num_sigs + 1] = bounds
+        seg_p = None
+        if dedup:
+            # >= num_sigs: sorts last, and falls out of the segment sum
+            seg_p = np.full(eb, nb, np.int32)
+            seg_p[:e] = seg
+        return p0, lab_p, tgt_p, bounds_p, seg_p, e, dedup
 
 
 @jax.jit
@@ -167,9 +170,9 @@ def _host_segsum_fold(lab_dev, tgt_p, seg, p0_vals, e: int, num_sigs: int):
     prefix sum).  Returns host (hi, lo) padded to ``bucket(num_sigs)``
     so downstream probe shapes match the all-device arrangement."""
     e_hi, e_lo = _edge_hash_pairs(lab_dev, jnp.asarray(tgt_p))
-    obs.event("maint.sync", what="edge_hash", edges=e)
-    e_hi = np.asarray(e_hi)[:e]
-    e_lo = np.asarray(e_lo)[:e]
+    with obs.span("maint.sync", what="edge_hash", edges=e):
+        e_hi = np.asarray(e_hi)[:e]
+        e_lo = np.asarray(e_lo)[:e]
     seg_hi = np.zeros(num_sigs, np.uint32)
     seg_lo = np.zeros(num_sigs, np.uint32)
     if e:
@@ -383,7 +386,7 @@ def _merge_step(khi, klo, kpid, *rest, new_cap: int):
 
 
 def _mint_misses(dstore, out: np.ndarray, qhi: np.ndarray, qlo: np.ndarray,
-                 next_pid: int) -> int:
+                 next_pid: int, level: "int | None" = None) -> int:
     """The host half of a resolve: the probe lanes the device store
     missed (``out < 0``) get pids minted exactly as
     `SigStore.get_or_assign` mints them (`mint_novel`: one per distinct
@@ -392,7 +395,8 @@ def _mint_misses(dstore, out: np.ndarray, qhi: np.ndarray, qlo: np.ndarray,
 
     Minting is a sort of the missing keys, which stays on the host: the
     device programs of maintenance hold no sort at all, so each new
-    frontier bucket compiles in seconds."""
+    frontier bucket compiles in seconds.  `level`, where the caller
+    knows it, labels the merge's span."""
     miss = out < 0
     if not miss.any():
         return next_pid
@@ -403,22 +407,22 @@ def _mint_misses(dstore, out: np.ndarray, qhi: np.ndarray, qlo: np.ndarray,
             "device store pid space exceeded int32; rebuild to "
             "re-densify pids")
     out[miss] = new_pids[inv]
-    dstore.insert_sorted(ukeys, new_pids)
+    dstore.insert_sorted(ukeys, new_pids, level=level)
     return next_pid + int(ukeys.shape[0])
 
 
 def _settle_level(dstore, qhi, qlo, out, old_pid, num_sigs: int,
-                  next_pid: int):
+                  next_pid: int, level: "int | None" = None):
     """A level whose pids changed: pull its probe lanes, mint the misses
     on host, merge them into the store.  Returns ((pids int64, changed
     bool, n_changed), next_pid')."""
-    obs.event("maint.sync", what="level_deltas", keys=num_sigs)
     # whole bucket-padded lanes, trimmed on host: slicing on device
     # would compile one more program per frontier length
-    out_h, qh, ql = (np.asarray(x)[:num_sigs]
-                     for x in jax.device_get((out, qhi, qlo)))
+    with obs.span("maint.sync", what="level_deltas", keys=num_sigs):
+        out_h, qh, ql = (np.asarray(x)[:num_sigs]
+                         for x in jax.device_get((out, qhi, qlo)))
     pj = out_h.astype(np.int64)
-    next_pid = _mint_misses(dstore, pj, qh, ql, next_pid)
+    next_pid = _mint_misses(dstore, pj, qh, ql, next_pid, level)
     changed = pj != np.asarray(old_pid)
     return (pj, changed, int(changed.sum())), next_pid
 
@@ -566,22 +570,25 @@ def resident_levels_resolve(dstores, pid0_vals, seg, elabel, tgts,
         old_stack, tuple((d.khi, d.klo, d.kpid) for d in dstores),
         np.asarray([d.size for d in dstores], np.int32))
     # THE steady-state sync: one k-vector of scalars for the whole loop
-    obs.event("maint.sync", what="levels_scalars", keys=num_sigs,
-              levels=k)
-    dirty_lvls = np.flatnonzero(np.asarray(jax.device_get(nchs_d)) > 0)
+    with obs.span("maint.sync", what="levels_scalars", keys=num_sigs,
+                  levels=k):
+        nchs = np.asarray(jax.device_get(nchs_d))
+    dirty_lvls = np.flatnonzero(nchs > 0)
     if dirty_lvls.size == 0:
         return k, None, None
     d = int(dirty_lvls[0])
     qhi, qlo, out = per_level[d]
     dirty, next_pid_d = _settle_level(dstores[d], qhi, qlo, out, olds[d],
-                                      num_sigs, int(next_pids[d]))
+                                      num_sigs, int(next_pids[d]),
+                                      level=d + 1)
     return d, dirty, next_pid_d
 
 
 def resident_level_resolve(dstore, pid0_vals, seg, elabel, pid_tgt,
                            num_sigs: int, old_pid, next_pid: int, *,
                            dedup: bool = True, bounds=None,
-                           cache: "dict | None" = None, cache_key=None):
+                           cache: "dict | None" = None, cache_key=None,
+                           level: "int | None" = None):
     """Fold + probe + changed count for one propagation level in one
     dispatch (the per-level residency path).
 
@@ -599,7 +606,7 @@ def resident_level_resolve(dstore, pid0_vals, seg, elabel, pid_tgt,
     ``cache``/``cache_key`` keep the multiset route's per-frontier device
     constants (pId_0, labels, boundaries) resident across levels, like
     `frontier_fold`'s cache (dedup modes reorder per level and bypass
-    it).
+    it).  ``level`` labels the store merge's span.
     """
     use_cache = cache is not None and cache_key is not None and not dedup
     if use_cache and cache.get("key") is not None \
@@ -636,11 +643,12 @@ def resident_level_resolve(dstore, pid0_vals, seg, elabel, pid_tgt,
         dstore.khi, dstore.klo, dstore.kpid, jnp.int32(dstore.size),
         jnp.asarray(old_p), jnp.int32(num_sigs))
     # THE steady-state sync: one scalar per level
-    obs.event("maint.sync", what="level_scalar", keys=num_sigs)
-    if int(n_changed_d) == 0:
+    with obs.span("maint.sync", what="level_scalar", keys=num_sigs):
+        n_changed = int(n_changed_d)
+    if n_changed == 0:
         return None, None, 0, next_pid
     (pj, changed, n_changed), next_pid = _settle_level(
-        dstore, qhi, qlo, out, old_pid, num_sigs, next_pid)
+        dstore, qhi, qlo, out, old_pid, num_sigs, next_pid, level)
     return pj, changed, n_changed, next_pid
 
 
@@ -704,21 +712,24 @@ class DeviceSigStore:
             out = _probe_step(
                 self.khi, self.klo, self.kpid, qhi, qlo, jnp.int32(count),
                 jnp.int32(self.size))
-            obs.event("maint.sync", what="probe", keys=count)
-            out_h, qh, ql = (np.asarray(x)[:count]
-                             for x in jax.device_get((out, qhi, qlo)))
+            with obs.span("maint.sync", what="probe", keys=count):
+                out_h, qh, ql = (np.asarray(x)[:count]
+                                 for x in jax.device_get((out, qhi, qlo)))
             out_h = out_h.astype(np.int64)
             nxt = _mint_misses(self, out_h, qh, ql, next_pid)
             sp.set(minted=nxt - next_pid)
         return out_h, nxt
 
-    def insert_sorted(self, ukeys: np.ndarray, pids: np.ndarray) -> None:
+    def insert_sorted(self, ukeys: np.ndarray, pids: np.ndarray, *,
+                      level: "int | None" = None) -> None:
         """Merge sorted, distinct keys that are not in the store (with
         their pids) into the device columns: one sort-free merge
         dispatch.  The capacity only grows, in power-of-two buckets; the
         novel batch is bucketed from `_NOVEL_FLOOR` up, so the store's
         merge compiles a handful of shapes per session (its cost is the
-        store's length, not the batch's)."""
+        store's length, not the batch's).  The `store.merge_device` span
+        times the dispatch only (the merge runs on after it returns) and
+        records the capacity the merge walks; `level` labels it."""
         n = int(ukeys.shape[0])
         p = bucket(n, _NOVEL_FLOOR)
         hi, lo = split_key(ukeys)
@@ -731,10 +742,13 @@ class DeviceSigStore:
         new_size = self.size + n
         cap = self.khi.shape[0]
         new_cap = cap if new_size <= cap else bucket(new_size)
-        obs.event("maint.dispatch", what="merge_insert", minted=n)
-        self.khi, self.klo, self.kpid = _merge_step(
-            self.khi, self.klo, self.kpid, nhi, nlo, npid, np.int32(n),
-            np.int32(self.size), new_cap=new_cap)
+        with obs.span("store.merge_device", minted=n, size=new_size,
+                      capacity=new_cap, bucket=p) as sp:
+            if level is not None:
+                sp.set(level=level)
+            self.khi, self.klo, self.kpid = _merge_step(
+                self.khi, self.klo, self.kpid, nhi, nlo, npid, np.int32(n),
+                np.int32(self.size), new_cap=new_cap)
         self.size = new_size
         self._host = None  # mirrored back lazily on extraction
 

@@ -244,9 +244,9 @@ class MaintenanceBackend(abc.ABC):
         """`resolve` over bucket-padded (hi, lo) hash lanes (only the
         first `count` are real) — the device fold feeds this without a
         host round-trip.  Default: fuse on host and resolve there."""
-        obs.event("maint.sync", what="fold_pairs", keys=count)
-        return self.resolve(
-            j, fuse_key(np.asarray(hi)[:count], np.asarray(lo)[:count]))
+        with obs.span("maint.sync", what="fold_pairs", keys=count):
+            hi, lo = np.asarray(hi)[:count], np.asarray(lo)[:count]
+        return self.resolve(j, fuse_key(hi, lo))
 
     def propagate_level_device(self, j: int, frontier: np.ndarray, *,
                                dedup: bool = True):
@@ -549,7 +549,7 @@ class InMemoryBackend(MaintenanceBackend):
             self._dstores[j], p0, seg, lab, pid_tgt, frontier.size,
             self.pids[j][frontier], self.next_pid[j], dedup=dedup,
             bounds=self._frontier_bounds(frontier),
-            cache=self._resident_cache, cache_key=frontier)
+            cache=self._resident_cache, cache_key=frontier, level=j)
         return out, changed, n_changed
 
     def propagate_levels_resident(self, frontier: np.ndarray, *,
@@ -946,7 +946,8 @@ class BisimMaintainer:
         with self._logged("add_edges", src=src, elabel=elabel, dst=dst):
             # the backend range-validates before mutating, so a rejected
             # insert must not re-animate anything
-            self.backend.add_edge_rows(src, elabel, dst)
+            with obs.span("maint.apply_edges", op="add", edges=src.size):
+                self.backend.add_edge_rows(src, elabel, dst)
             # an edge incident to a tombstoned node re-animates it
             self._tombstone[src] = False
             self._tombstone[dst] = False
@@ -961,7 +962,9 @@ class BisimMaintainer:
         dst = np.atleast_1d(np.asarray(dst, dtype=np.int32))
         elabel = np.atleast_1d(np.asarray(elabel, dtype=np.int32))
         with self._logged("delete_edges", src=src, elabel=elabel, dst=dst):
-            self.backend.remove_edge_rows(src, elabel, dst)
+            with obs.span("maint.apply_edges", op="delete",
+                          edges=src.size):
+                self.backend.remove_edge_rows(src, elabel, dst)
             return self._propagate(frontier0=np.unique(src))
 
     def delete_node(self, nid: int) -> MaintenanceReport:

@@ -24,9 +24,10 @@ levels:
 
 Both arrangements run the same integer ops in the same order, so their pid
 histories and counts are bit-identical (asserted by the parity sweep in
-tests/test_fused_build.py).  Every device->host drain emits a
-``build.sync`` tracer event and every program launch a ``build.dispatch``
-event, so a ``--trace`` run shows the dispatch/sync count per build.
+tests/test_fused_build.py).  Every device->host drain runs inside a
+``build.sync`` tracer span and every program launch emits a
+``build.dispatch`` event, so a ``--trace`` run shows the dispatch/sync
+count per build and the host time blocked on each sync.
 
 The signature store S is extracted from the already-computed (hi, lo)
 arrays with zero Python loops: each level's store is an array-backed sorted
@@ -214,10 +215,11 @@ def build_bisim(graph: Graph, k: int, *, mode: str = "sorted",
         raise ValueError("fused build cannot materialize per-level stores; "
                          "use the staged sync_every path (fused=None/False)")
     n = graph.num_nodes
-    node_labels = jnp.asarray(graph.node_labels)
-    src = jnp.asarray(graph.src)
-    dst = jnp.asarray(graph.dst)
-    elabel = jnp.asarray(graph.elabel)
+    with obs.span("build.upload", nodes=n, edges=graph.num_edges):
+        node_labels = jnp.asarray(graph.node_labels)
+        src = jnp.asarray(graph.src)
+        dst = jnp.asarray(graph.dst)
+        elabel = jnp.asarray(graph.elabel)
     esize = max(graph.num_edges, 1)
     key_bytes = {"sorted": 12, "dedup_hash": 12, "multiset": 0}[mode]
 
@@ -232,8 +234,8 @@ def build_bisim(graph: Graph, k: int, *, mode: str = "sorted",
     t0 = time.perf_counter()
     obs.event("build.dispatch", path="staged", what="iteration0")
     pid0, count0 = _iteration0(node_labels)
-    obs.event("build.sync", path="staged", what="count0")
-    c0 = int(count0)  # host sync point for the timing below
+    with obs.span("build.sync", path="staged", what="count0"):
+        c0 = int(count0)  # host sync point for the timing below
     stats = [IterationStats(0, c0, time.perf_counter() - t0,
                             bytes_sorted=4 * n, bytes_scanned=4 * n)]
     counts = [c0]
@@ -255,9 +257,9 @@ def build_bisim(graph: Graph, k: int, *, mode: str = "sorted",
         if not pending:
             return converged_at is not None
         t_sync = time.perf_counter()
-        obs.event("build.sync", path="staged", what="drain",
-                  batched=len(pending))
-        host = jax.device_get([(c, f) for _, c, f, _ in pending])
+        with obs.span("build.sync", path="staged", what="drain",
+                      batched=len(pending)):
+            host = jax.device_get([(c, f) for _, c, f, _ in pending])
         # The device_get wait is where the batched steps' compute is paid
         # for; amortize it over the drained iterations so per-iteration
         # seconds stay meaningful (sum over stats ~ wall time, as with
@@ -304,8 +306,8 @@ def build_bisim(graph: Graph, k: int, *, mode: str = "sorted",
         sig_pairs = sig_pairs[:keep - 1]
 
     # Single bulk host transfer of the pid history (+ signatures if stored).
-    obs.event("build.sync", path="staged", what="history")
-    pids_host, sig_host = jax.device_get((history, sig_pairs))
+    with obs.span("build.sync", path="staged", what="history"):
+        pids_host, sig_host = jax.device_get((history, sig_pairs))
     pids = np.stack([np.asarray(p) for p in pids_host])
 
     stores, next_pid = None, None
@@ -333,12 +335,13 @@ def _build_fused(graph: Graph, k: int, node_labels, src, dst, elabel, *,
         node_labels, src, dst, elabel, k=k, num_nodes=n, mode=mode,
         use_kernel=use_kernel, early_stop=early_stop)
     # THE device->host sync: history, counts and the two loop scalars in
-    # one transfer (build.sync_count == 1 for the whole build).
-    hist, cnts, iters, conv = jax.device_get(
-        (hist_d, cnts_d, iters_d, conv_d))
+    # one transfer (one build.sync span for the whole build).
+    with obs.span("build.sync", path="fused", what="history") as sp:
+        hist, cnts, iters, conv = jax.device_get(
+            (hist_d, cnts_d, iters_d, conv_d))
+        iters = int(iters)
+        sp.set(iterations=iters)
     dt = time.perf_counter() - t0
-    iters = int(iters)
-    obs.event("build.sync", path="fused", what="history", iterations=iters)
 
     converged_at = int(conv) if early_stop and int(conv) >= 0 else None
     keep = iters + 1  # converged loops stop right after the fixpoint step

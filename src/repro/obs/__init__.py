@@ -17,28 +17,53 @@ Span taxonomy (``layer.phase``):
 * ``build.*``    — `build_bisim_oocore` per-level phases, each carrying
   ``level=j``: ``build.level`` (whole level, with IOStats deltas),
   ``build.join``, ``build.fold``, ``build.rank``, ``build.pid_write``.
+  The in-memory `build_bisim`: ``build.upload`` (the graph's four
+  columns to the device, ``nodes=``, ``edges=``), ``build.sync`` (each
+  device->host transfer, ``path=`` and ``what=``; the fused build has
+  exactly one) and the ``build.dispatch`` instant event per program
+  launch.
 * ``sort.*``     — `exmem.runs` external sort: ``sort.run_formation``
   (one span per formed run), ``sort.merge_pass`` / ``sort.merge_chunk``
   (k-way fan-in), ``sort.merge_to_file``.
 * ``store.*``    — `SpillableSigStore` / `DeviceSigStore`:
   ``store.probe``, ``store.resolve`` (probe+mint, ``minted=`` attr),
   ``store.spill``, ``store.merge``, ``store.probe_device``,
-  ``store.resolve_device``.
+  ``store.resolve_device``, ``store.merge_device`` (the dispatch of the
+  device store's merge, which runs on after the span ends: ``minted=``,
+  ``size=`` and ``capacity=`` after the merge, the novel ``bucket=``,
+  and ``level=`` where the caller knows it).
 * ``table.*``    — on-disk table scans/rewrites: ``table.scan`` (per
   chunk, on the prefetch reader lane), ``table.rewrite``.
 * ``aio.*``      — async pipeline threads: ``aio.read_chunk`` (reader
   lane), ``aio.write_chunk`` (writer lane), ``aio.readahead`` /
   ``aio.save`` (pool lanes), and consumer-side ``aio.wait_read`` /
   ``aio.wait_write`` wait attribution.
-* ``maint.*``    — `BisimMaintainer` propagation: ``maint.propagate``
-  per update, ``maint.level`` per level (``level=``, ``frontier=``,
-  ``device=`` attrs), ``maint.rebuild``.
+* ``maint.*``    — `BisimMaintainer` updates: ``maint.apply_edges``
+  (the backend's edge-table rewrite, ``op=``, ``edges=``), then
+  ``maint.propagate`` per update, ``maint.level`` per level
+  (``level=``, ``frontier=``, ``device=`` attrs), ``maint.rebuild``;
+  on the device path ``maint.prepare`` (host dedup and padding of a
+  frontier batch, ``edges=``, ``dedup=``), ``maint.sync`` (each
+  device->host transfer, ``what=``) and the ``maint.dispatch`` instant
+  event per program launch.
+* ``quotient.*`` — `repro.quotient`: ``quotient.materialize`` and
+  ``quotient.level`` (index build), ``quotient.patch`` (a maintenance
+  batch absorbed; the ``quotient.epoch`` instant event marks the swap),
+  ``quotient.query_wave`` per wave of path queries, inside it
+  ``quotient.sync`` (the wave's mask fetch, ``bytes=``) and
+  ``quotient.expand`` (mask to node ids, ``queries=``, ``nodes=``).
 * ``wal.*``      — durability: ``wal.append``, ``wal.commit`` (fsync
   round), ``wal.replay``, ``wal.snapshot``, ``wal.restore``.
 * ``fault.*``    — instant *events*, not spans: ``fault.point`` (each
   fired injection point), ``fault.transient`` / ``fault.crash`` /
   ``fault.torn`` (what the plan injected), ``fault.retry`` (each
   `with_retries` backoff).
+
+A ``<layer>.sync`` span wraps one blocking device->host transfer
+(``jax.device_get``, ``np.asarray`` or ``int`` of a device array): its
+duration is the host time spent waiting on the device, which includes
+whatever dispatched work the transfer waited for.  No span adds a sync
+of its own.
 
 Usage::
 

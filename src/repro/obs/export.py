@@ -179,8 +179,8 @@ class MetricsReport:
         self.span_count = span_count
         self.event_count = event_count
         self.dropped = dropped
-        # instant-event counts per name (e.g. build.sync / build.dispatch:
-        # the device round-trip counters the fused-loop work is judged by)
+        # instant-event counts per name (e.g. build.dispatch: the program
+        # launches; the *.sync transfers are spans, under `phases`)
         self.events = events or {}
 
     @classmethod

@@ -174,8 +174,14 @@ class QuotientEngine:
                 mask = _hop(mask, src, el, dst, jnp.asarray(lab_t),
                             n_src=view.counts[lev])
                 self.stats["hops"] += 1
-            host = np.asarray(mask)  # the wave's one device->host sync
+            # the wave's one device->host sync
+            with obs.span("quotient.sync", bytes=mask.nbytes):
+                host = np.asarray(mask)
             self.stats["waves"] += 1
-            for s, (i, _, src_l, _) in enumerate(wave):
-                answers[i] = expand_blocks(view, j, host[s], src_l)
-                self.stats["queries"] += 1
+            with obs.span("quotient.expand", queries=len(wave)) as sp:
+                nodes = 0
+                for s, (i, _, src_l, _) in enumerate(wave):
+                    answers[i] = expand_blocks(view, j, host[s], src_l)
+                    nodes += answers[i].size
+                    self.stats["queries"] += 1
+                sp.set(nodes=nodes)

@@ -74,7 +74,7 @@ def test_fused_build_single_sync():
     g = GRAPHS["powerlaw"]()
     with obs.tracing() as tracer:
         partition.build_bisim(g, 6, mode="multiset", fused=True)
-    syncs = tracer.find_events("build.sync")
+    syncs = tracer.find("build.sync")
     dispatches = tracer.find_events("build.dispatch")
     assert len(syncs) == 1
     assert len(dispatches) == 1
@@ -88,7 +88,7 @@ def test_staged_build_sync_count_scales_with_sync_every():
         with obs.tracing() as tracer:
             partition.build_bisim(g, 6, mode="multiset", fused=False,
                                   sync_every=sync_every)
-        counts[sync_every] = len(tracer.find_events("build.sync"))
+        counts[sync_every] = len(tracer.find("build.sync"))
     assert counts[1] > counts[3] >= 1
 
 

@@ -425,3 +425,148 @@ def test_quotient_serving_bit_identical_with_tracing(tmp_path):
     epochs = tracer.find_events("quotient.epoch")
     assert [e["attrs"]["epoch"] for e in epochs] == [1]
     _assert_no_aio_threads()
+
+
+# ------------------------------------- host<->device boundary spans
+def _within(tracer, rec, outer: str) -> bool:
+    """`rec` ran inside a span named `outer` on the same thread."""
+    return any(o["tid"] == rec["tid"] and o["depth"] < rec["depth"]
+               and o["ts"] <= rec["ts"]
+               and rec["ts"] + rec["dur"] <= o["ts"] + o["dur"]
+               for o in tracer.find(outer))
+
+
+@pytest.mark.parametrize("fused", [True, False])
+def test_in_memory_build_upload_and_sync_spans(fused):
+    """`build_bisim` names its upload and each device->host transfer as
+    spans inside the caller's; outputs are bit-identical with tracing on
+    and off, and the fused build records exactly one `build.sync`."""
+    from repro.core import build_bisim
+    g = gen.random_graph(500, 1500, 4, 3, seed=7)
+    off = build_bisim(g, 6, fused=fused, sync_every=2)
+    with tracing() as t:
+        with t.span("test.build"):
+            on = build_bisim(g, 6, fused=fused, sync_every=2)
+    np.testing.assert_array_equal(off.pids, on.pids)
+    assert off.counts == on.counts and off.converged_at == on.converged_at
+    uploads = t.find("build.upload")
+    assert len(uploads) == 1 and uploads[0]["parent"] == "test.build"
+    assert uploads[0]["attrs"] == {"nodes": g.num_nodes,
+                                   "edges": g.num_edges}
+    syncs = t.find("build.sync")
+    assert syncs and all(s["parent"] == "test.build" for s in syncs)
+    assert not t.find_events("build.sync")      # spans, not events
+    if fused:
+        assert len(syncs) == 1
+        assert syncs[0]["attrs"] == {"path": "fused", "what": "history",
+                                     "iterations": len(on.pids) - 1}
+    else:
+        whats = [s["attrs"]["what"] for s in syncs]
+        assert whats[0] == "count0" and whats[-1] == "history"
+        assert set(whats[1:-1]) == {"drain"}
+        assert all(s["attrs"]["path"] == "staged" for s in syncs)
+    # the report lists the syncs as phases, with count and time
+    rep = MetricsReport.from_tracer(t)
+    assert rep.phases["build.sync"]["count"] == len(syncs)
+    assert rep.phases["build.sync"]["total_s"] > 0
+    assert "build.sync" not in rep.events and rep.events["build.dispatch"]
+    assert "build.sync" in rep.format()
+
+
+def _updates(m, rng, batches=4, size=12):
+    n = m.backend.num_nodes
+    for b in range(batches):
+        src = rng.integers(0, n, size).astype(np.int32)
+        dst = rng.integers(0, n, size).astype(np.int32)
+        lab = rng.integers(0, 3, size).astype(np.int32)
+        if b % 4 == 3:                   # delete edges that are there
+            g = m.graph
+            pick = rng.choice(g.num_edges, size, replace=False)
+            src, lab, dst = g.src[pick], g.elabel[pick], g.dst[pick]
+            m.delete_edges(src, lab, dst)
+        else:
+            m.add_edges(src, lab, dst)
+
+
+@pytest.mark.parametrize("mode", MODES)
+def test_device_maintenance_spans_and_bit_identity(mode):
+    """Device maintenance under tracing: the same pids as untraced, each
+    batch's edge rewrite in a `maint.apply_edges` span, and the host
+    prep, the transfers and the store merges inside `maint.propagate`."""
+    g = gen.random_graph(400, 1200, 4, 3, seed=5)
+
+    def _run(traced):
+        m = BisimMaintainer(g, 3, mode=mode, device=True)
+        rng = np.random.default_rng(19)
+        t = Tracer()
+        if traced:
+            with tracing(t):
+                _updates(m, rng)
+        else:
+            _updates(m, rng)
+        assert m.device
+        return [p.copy() for p in m.pids], t
+
+    pids_off, _ = _run(False)
+    pids_on, t = _run(True)
+    for a, b in zip(pids_off, pids_on):
+        np.testing.assert_array_equal(a, b)
+    applies = t.find("maint.apply_edges")
+    assert [s["attrs"]["op"] for s in applies] == ["add"] * 3 + ["delete"]
+    assert all(s["attrs"]["edges"] == 12 for s in applies)
+    assert len(t.find("maint.propagate")) == len(applies)
+    prepares = t.find("maint.prepare")
+    assert prepares
+    for s in prepares:
+        assert set(s["attrs"]) == {"edges", "dedup"}
+        assert s["attrs"]["dedup"] == (mode != "multiset")
+        assert _within(t, s, "maint.propagate")
+    syncs = t.find("maint.sync")
+    assert syncs and not t.find_events("maint.sync")
+    for s in syncs:
+        assert s["attrs"]["what"] in {"levels_scalars", "level_scalar",
+                                      "level_deltas", "probe"}
+        assert _within(t, s, "maint.propagate")
+    merges = t.find("store.merge_device")
+    assert merges
+    for s in merges:
+        a = s["attrs"]
+        assert a["minted"] > 0 and a["size"] <= a["capacity"]
+        assert a["bucket"] >= a["minted"] and 1 <= a["level"] <= 3
+        assert _within(t, s, "maint.propagate")
+    # the merge dispatch is a span now; the other dispatches stay events
+    assert not [e for e in t.find_events("maint.dispatch")
+                if e["attrs"]["what"] == "merge_insert"]
+    assert t.find_events("maint.dispatch")
+
+
+def test_quotient_sync_and_expand_spans(tmp_path):
+    """Each wave's mask fetch and expansion are spans inside its
+    `quotient.query_wave`, with the fetch's bytes, the wave's query count
+    and the answers' node count; answers match the untraced engine's."""
+    from repro.quotient import (LabelPath, QuotientEngine, ReachTemplate,
+                                materialize_quotient)
+    g = gen.random_graph(300, 900, 3, 3, seed=4)
+    m = BisimMaintainer(g, 3)
+    index = materialize_quotient(g, m.backend, str(tmp_path / "q"),
+                                 counts=[int(x) for x in m.next_pid])
+    queries = [LabelPath((0,), level=1), LabelPath((1, 2), level=3),
+               ReachTemplate((2,), src_label=0, level=2),
+               LabelPath((0, 1), level=2), LabelPath((2,), level=1)]
+    off = QuotientEngine(index, max_batch=2).query(queries)
+    engine = QuotientEngine(index, max_batch=2)
+    with tracing() as t:
+        on = engine.query(queries)
+    for a, b in zip(off, on):
+        np.testing.assert_array_equal(a, b)
+    waves = t.find("quotient.query_wave")
+    syncs, expands = t.find("quotient.sync"), t.find("quotient.expand")
+    assert len(syncs) == len(expands) == len(waves) == engine.stats["waves"]
+    for s in syncs + expands:
+        assert s["parent"] == "quotient.query_wave"
+    for w, s, e in zip(waves, syncs, expands):
+        # the mask is bool [max_batch, blocks of the wave's level]
+        assert s["attrs"]["bytes"] == 2 * index.counts[w["attrs"]["level"]]
+        assert e["attrs"]["queries"] == w["attrs"]["batch"]
+    assert sum(e["attrs"]["nodes"] for e in expands) == \
+        sum(a.size for a in on)
