@@ -4,24 +4,38 @@ wave idiom applied to structural queries.
 Path queries are bucketed by (level, hop count): every query in a
 bucket walks the same level ladder, so a wave of up to ``max_batch``
 of them shares ONE jitted dispatch per hop (a [B, n_blocks] block mask
-advanced by a scatter-max over the level's device-resident edge
-triples) and ONE device->host sync per wave (the final mask fetch).
-Padding slots carry the WANT_NONE sentinel label, which matches no
-block.  Point lookups never touch the device: they are host
-`searchsorted` over the extent runs.
+advanced over the level's device-resident edge columns) and ONE
+device->host sync per wave (the final mask fetch).  Padding slots carry
+the WANT_NONE sentinel label, which matches no block.  Point lookups
+never touch the device: they are host `searchsorted` over the extent
+runs.
+
+A hop needs no scatter.  Each level's edges are in canonical (src,
+elabel, dst) order, so the out-edges of source block P are the
+contiguous run [off[P], off[P+1]) of the level's CSR offsets, computed
+on the host at `refresh` (which refuses a level whose `src` is not
+non-decreasing: the hop rests on it).  "Some out-edge of P is a hit" is
+then a prefix sum of the [B, Eq] hit matrix differenced at the offsets.
+The prefix sum runs in blocks of ``_SCAN_BLOCK`` edges, an int16 scan
+inside each block plus an int32 scan of the block totals: XLA's
+one-axis cumsum over millions of edges compiles for a TPU in anything
+from one second to minutes, depending on the length.  The hop holds
+the [B, Eq] bool hits and their [B, Eq] int16 in-block sums (plus the
+compiler's relayouts): for a v5e the compiler reserves 1.3 GB at B = 16
+and 6M quotient edges, 2.3 GB at the default ``max_batch`` of 64 — well
+inside one chip's 16 GB.
 
 The compiled-program cache is keyed by the level shapes, so a steady
 artifact compiles O(k) hop programs once; a maintenance patch that
-changes a level's edge count recompiles that level's hop only.
+changes a level's edge or block count recompiles that level's hop only.
 
 Engine answers are bit-identical to `queries.eval_ref`: both compute
-the same boolean masks (the device scatter-max is exact on bools) and
-share `expand_blocks` for the mask -> node-id step — asserted by the
-differential tests.
+the same boolean masks (an integer count of hits per block is exact,
+and > 0 is the OR) and share `expand_blocks` for the mask -> node-id
+step — asserted by the differential tests.
 """
 from __future__ import annotations
 
-import functools
 from typing import Dict, List
 
 import jax
@@ -41,14 +55,41 @@ def _init_mask(labels: jnp.ndarray, want: jnp.ndarray) -> jnp.ndarray:
     return (want[:, None] == WANT_ALL) | (labels[None, :] == want[:, None])
 
 
-@functools.partial(jax.jit, static_argnames=("n_src",))
-def _hop(mask_tgt: jnp.ndarray, src: jnp.ndarray, elabel: jnp.ndarray,
-         dst: jnp.ndarray, want: jnp.ndarray, *, n_src: int) -> jnp.ndarray:
+# edges per block of the hop's prefix sum; an in-block sum fits int16
+_SCAN_BLOCK = 1024
+# the label of the hop's padding edges: equal to no label a hop asks for
+_NO_LABEL = np.iinfo(np.int32).min
+
+
+@jax.jit
+def _hop(mask_tgt: jnp.ndarray, elabel: jnp.ndarray, dst: jnp.ndarray,
+         off: jnp.ndarray, want: jnp.ndarray) -> jnp.ndarray:
     """One backward hop for a whole wave: block P survives for slot b
-    iff some edge (P, want[b], Q) has mask_tgt[b, Q]."""
-    hit = mask_tgt[:, dst] & (elabel[None, :] == want[:, None])
-    return jnp.zeros((mask_tgt.shape[0], n_src),
-                     dtype=jnp.bool_).at[:, src].max(hit)
+    iff some edge (P, want[b], Q) has mask_tgt[b, Q].
+
+    The level's edges are src-sorted and P's out-edges are the run
+    [off[P], off[P+1]), so P's hit count is a difference of the prefix
+    sum of the [B, Eq] hits, gathered once at the n_src + 1 offsets.
+    One padding edge in front makes the sum at index i count the hits
+    before edge i; the tail pads to whole blocks and is never read."""
+    b, e = mask_tgt.shape[0], dst.shape[0]
+    pad = (1, -(e + 1) % _SCAN_BLOCK)
+    el = jnp.pad(elabel, pad, constant_values=_NO_LABEL)
+    hit = mask_tgt[:, jnp.pad(dst, pad)] & (el[None, :] == want[:, None])
+    inner = jnp.cumsum(hit.reshape(b, -1, _SCAN_BLOCK), axis=2,
+                       dtype=jnp.int16)
+    tot = inner[:, :, -1].astype(jnp.int32)
+    base = jnp.cumsum(tot, axis=1) - tot
+    at = inner.reshape(b, -1)[:, off] + base[:, off // _SCAN_BLOCK]
+    return at[:, 1:] > at[:, :-1]
+
+
+def _src_offsets(src: np.ndarray, n_src: int) -> np.ndarray:
+    """int32 [n_src + 1] CSR offsets of a src-sorted edge column: the
+    out-edges of block P are [off[P], off[P+1])."""
+    if src.size and np.any(src[1:] < src[:-1]):
+        raise ValueError("quotient level edges are not sorted by src")
+    return np.searchsorted(src, np.arange(n_src + 1)).astype(np.int32)
 
 
 class _EpochView:
@@ -98,8 +139,9 @@ class QuotientEngine:
 
     # ------------------------------------------------------------ snapshot
     def refresh(self, levels=None) -> None:
-        """(Re-)upload level edge triples and block labels; with
-        ``levels`` only those (a patch's touched set), else all.  The
+        """(Re-)upload level edge columns, their src offsets and block
+        labels; with ``levels`` only those (a patch's touched set), else
+        all.  Raises ValueError on a level not sorted by src.  The
         caller patches the host index first (copy-on-write: pinned
         arrays are never scribbled on); this swap is the one atomic
         point where new queries start seeing the new epoch."""
@@ -109,9 +151,8 @@ class QuotientEngine:
         lvls = range(1, idx.k + 1) if levels is None else sorted(levels)
         for j in lvls:
             L = idx.levels[j]
-            dev_levels[j] = (jnp.asarray(L.src),
-                             jnp.asarray(L.elabel),
-                             jnp.asarray(L.dst))
+            dev_levels[j] = (jnp.asarray(L.elabel), jnp.asarray(L.dst),
+                             jnp.asarray(_src_offsets(L.src, idx.counts[j])))
         labs = range(idx.k + 1) if levels is None else sorted(
             set(levels) | {j - 1 for j in levels})
         for j in labs:
@@ -167,12 +208,12 @@ class QuotientEngine:
             mask = _init_mask(view.dev_labels[j - m], jnp.asarray(want))
             for t in range(m - 1, -1, -1):
                 lev = j - t
-                src, el, dst = view.dev_levels[lev]
+                el, dst, off = view.dev_levels[lev]
+                assert off.shape[0] == view.counts[lev] + 1
                 lab_t = np.full(B, WANT_NONE, dtype=np.int32)
                 for s, (_, labels, _, _) in enumerate(wave):
                     lab_t[s] = labels[t]
-                mask = _hop(mask, src, el, dst, jnp.asarray(lab_t),
-                            n_src=view.counts[lev])
+                mask = _hop(mask, el, dst, off, jnp.asarray(lab_t))
                 self.stats["hops"] += 1
             # the wave's one device->host sync
             with obs.span("quotient.sync", bytes=mask.nbytes):

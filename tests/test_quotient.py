@@ -16,7 +16,10 @@ epoch/staleness contract under an interleaved update/query stream
 and the patch cost staying far below full rematerialization.
 """
 import os
+import re
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -127,6 +130,80 @@ def test_engine_batching_is_order_and_width_invariant(tmp_path):
     for slot, i in enumerate(perm):
         np.testing.assert_array_equal(a1[i], a2[slot])
     assert narrow.stats["waves"] > wide.stats["waves"]
+
+
+# ------------------------------------------------------------ device hop
+def _random_level(rng, n_src, n_tgt, n_edges, n_labels, src_hi):
+    """Random src-sorted edge columns; sources drawn below `src_hi`, so
+    blocks in [src_hi, n_src) have no out-edges."""
+    src = np.sort(rng.integers(0, max(src_hi, 1), n_edges)).astype(np.int32)
+    return (src, rng.integers(0, n_labels, n_edges).astype(np.int32),
+            rng.integers(0, n_tgt, n_edges).astype(np.int32))
+
+
+@pytest.mark.parametrize("b,n_edges,src_hi", [
+    (1, 0, 0),          # an empty level
+    (16, 0, 0),
+    (1, 300, 60),       # sparse: many blocks with no out-edges
+    (16, 300, 40),      # trailing blocks above max(src)
+    (16, 5000, 64),     # dense: long runs per block
+    (4, 1023, 64),      # the scan's blocks: exactly one, full
+    (4, 2048, 64),      # one edge into a third block
+])
+def test_hop_matches_scatter_or_reference(b, n_edges, src_hi):
+    """The prefix-sum hop against `np.logical_or.at` over the same hits,
+    with padding (WANT_NONE) and WANT_ALL slots among real labels."""
+    from repro.quotient.engine import _hop, _src_offsets
+    from repro.quotient.queries import WANT_ALL, WANT_NONE
+    rng = np.random.default_rng(1000 * b + n_edges + src_hi)
+    n_src, n_tgt, n_labels = 64, 48, 3
+    src, el, dst = _random_level(rng, n_src, n_tgt, n_edges, n_labels,
+                                 src_hi)
+    mask = rng.random((b, n_tgt)) < 0.3
+    want = rng.integers(0, n_labels, b).astype(np.int32)
+    want[max(b // 2, 1):] = WANT_NONE
+    if b > 1:
+        want[-1] = WANT_ALL
+    got = np.asarray(_hop(mask, el, dst, _src_offsets(src, n_src), want))
+    hit = mask[:, dst] & (el[None, :] == want[:, None])
+    ref = np.zeros((b, n_src), bool)
+    rows = np.repeat(np.arange(b), n_edges)
+    np.logical_or.at(ref, (rows, np.tile(src, b)), hit.ravel())
+    np.testing.assert_array_equal(got, ref)
+    assert ref.any() == (n_edges > 0)
+    assert not got[want < 0].any()
+    assert not got[:, max(src_hi, 1):].any()
+
+
+def test_hop_has_no_scatter():
+    """The hop is a gather, a prefix sum and a gather at the offsets:
+    neither its lowered program nor the compiled one holds a scatter."""
+    from repro.quotient.engine import _hop
+    b, n_tgt, n_src, eq = 4, 32, 24, 100
+    lowered = _hop.lower(
+        jax.ShapeDtypeStruct((b, n_tgt), jnp.bool_),
+        jax.ShapeDtypeStruct((eq,), jnp.int32),
+        jax.ShapeDtypeStruct((eq,), jnp.int32),
+        jax.ShapeDtypeStruct((n_src + 1,), jnp.int32),
+        jax.ShapeDtypeStruct((b,), jnp.int32))
+    # as a word: the test's own name is in the programs' source metadata
+    for text in (lowered.as_text(), lowered.compile().as_text()):
+        assert not re.search(r"\bscatter\b", text)
+
+
+def test_refresh_rejects_level_not_sorted_by_src(tmp_path):
+    g = GENERATORS["random"]()
+    m = BisimMaintainer(g, 2)
+    index = materialize_quotient(g, m.backend, str(tmp_path / "q"),
+                                 counts=[int(x) for x in m.next_pid])
+    engine = QuotientEngine(index, max_batch=2)
+    L = index.levels[1]
+    assert L.num_edges > 1 and L.src[0] < L.src[-1]
+    index.levels[1] = type(L)(L.src[::-1].copy(), L.elabel, L.dst)
+    with pytest.raises(ValueError, match="not sorted by src"):
+        engine.refresh([1])
+    with pytest.raises(ValueError, match="not sorted by src"):
+        QuotientEngine(index)
 
 
 def test_normalize_query_validation():

@@ -12,6 +12,7 @@ The topology is described inside a module fixture, never at import:
 only one process may hold the TPU library at a time.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -126,11 +127,15 @@ def test_levels_resident_step_compiles(spec):
         spec((k,))).compile()
 
 
-def test_quotient_hop_compiles(spec):
+@pytest.mark.parametrize("b,blocks,edges", [
+    (16, 1 << 12, 1 << 14),
+    (16, 1 << 21, 1 << 23),     # the query cell's level shape
+])
+def test_quotient_hop_compiles(spec, b, blocks, edges):
     from repro.quotient.engine import _hop
-    b, blocks, edges = 16, 1 << 12, 1 << 14
-    _hop.lower(spec((b, blocks), jnp.bool_), spec((edges,)), spec((edges,)),
-               spec((edges,)), spec((b,)), n_src=blocks).compile()
+    c = _hop.lower(spec((b, blocks), jnp.bool_), spec((edges,)),
+                   spec((edges,)), spec((blocks + 1,)), spec((b,))).compile()
+    assert not re.search(r"\bscatter\b", c.as_text())
 
 
 def test_fused_build_compiles(spec):
